@@ -25,11 +25,15 @@ Usage: python -m benchmarks.run [table4|fig14|...|all]
 """
 from __future__ import annotations
 
+import pathlib
 import sys
 import time
 
 
 def main() -> None:
+    from repro import compile_cache
+
+    compile_cache.enable(pathlib.Path(__file__).resolve().parent.parent)
     from benchmarks import (
         bench_bootstrap, bench_keyswitch, bench_pallas, bench_runtime,
         bench_serving, bench_workloads, common, fig6_parallelism,

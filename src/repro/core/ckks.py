@@ -46,7 +46,8 @@ class Plaintext:
     scale: float
 
 
-def tensor_product(a: Ciphertext, b: Ciphertext, mods) -> tuple:
+def tensor_product(a: Ciphertext, b: Ciphertext, mods,
+                   pc: poly.PolyContext) -> tuple:
     """(d0, d1, d2) of the degree-2 ciphertext product, pre-relin.
 
     The single call site for the CMult tensor product: both the eager
@@ -55,11 +56,9 @@ def tensor_product(a: Ciphertext, b: Ciphertext, mods) -> tuple:
     relin keyswitch always sees identical operands.  Elementwise mod-q
     ops broadcast over an optional leading batch axis unchanged.
     """
-    d0 = poly.mul(a.c0, b.c0, mods)
-    d1 = poly.add(
-        poly.mul(a.c0, b.c1, mods), poly.mul(a.c1, b.c0, mods), mods
-    )
-    d2 = poly.mul(a.c1, b.c1, mods)
+    d0 = pc.mul(a.c0, b.c0, mods)
+    d1 = pc.add(pc.mul(a.c0, b.c1, mods), pc.mul(a.c1, b.c0, mods), mods)
+    d2 = pc.mul(a.c1, b.c1, mods)
     return d0, d1, d2
 
 
@@ -131,7 +130,7 @@ class CKKSContext:
             primes, self.pc,
         )
         s = self._sk_rows(level)
-        b = poly.add(poly.sub(e, poly.mul(a, s, mods), mods), pt.m, mods)
+        b = self.pc.add(self.pc.sub(e, self.pc.mul(a, s, mods), mods), pt.m, mods)
         return Ciphertext(c0=b, c1=a, level=level, scale=pt.scale)
 
     def _sk_rows(self, level: int) -> jnp.ndarray:
@@ -140,8 +139,8 @@ class CKKSContext:
     def decrypt(self, ct: Ciphertext) -> np.ndarray:
         primes = self.chain(ct.level)
         mods = self.pc.mods(primes)
-        m_eval = poly.add(
-            ct.c0, poly.mul(ct.c1, self._sk_rows(ct.level), mods), mods
+        m_eval = self.pc.add(
+            ct.c0, self.pc.mul(ct.c1, self._sk_rows(ct.level), mods), mods
         )
         m_coeff = poly.intt(m_eval, primes, self.pc)
         return self.encoder.decode(np.asarray(m_coeff), ct.scale, primes)
@@ -215,7 +214,7 @@ class CKKSContext:
         self._require_same_level(a, b, "add")
         mods = self.pc.mods(self.chain(a.level))
         return Ciphertext(
-            poly.add(a.c0, b.c0, mods), poly.add(a.c1, b.c1, mods),
+            self.pc.add(a.c0, b.c0, mods), self.pc.add(a.c1, b.c1, mods),
             a.level, a.scale,
         )
 
@@ -223,7 +222,7 @@ class CKKSContext:
         self._require_same_level(a, b, "sub")
         mods = self.pc.mods(self.chain(a.level))
         return Ciphertext(
-            poly.sub(a.c0, b.c0, mods), poly.sub(a.c1, b.c1, mods),
+            self.pc.sub(a.c0, b.c0, mods), self.pc.sub(a.c1, b.c1, mods),
             a.level, a.scale,
         )
 
@@ -231,7 +230,7 @@ class CKKSContext:
         self._require_pt_level(a, pt, "pt_add")
         mods = self.pc.mods(self.chain(a.level))
         return Ciphertext(
-            poly.add(a.c0, pt.m[: a.n_limbs], mods), a.c1, a.level, a.scale
+            self.pc.add(a.c0, pt.m[: a.n_limbs], mods), a.c1, a.level, a.scale
         )
 
     def pt_mul(self, a: Ciphertext, pt: Plaintext,
@@ -239,8 +238,8 @@ class CKKSContext:
         self._require_pt_level(a, pt, "pt_mul")
         mods = self.pc.mods(self.chain(a.level))
         out = Ciphertext(
-            poly.mul(a.c0, pt.m[: a.n_limbs], mods),
-            poly.mul(a.c1, pt.m[: a.n_limbs], mods),
+            self.pc.mul(a.c0, pt.m[: a.n_limbs], mods),
+            self.pc.mul(a.c1, pt.m[: a.n_limbs], mods),
             a.level, a.scale * pt.scale,
         )
         return self.rescale(out) if rescale else out
@@ -323,10 +322,10 @@ class CKKSContext:
         acc0 = acc1 = None
         for j, d in enumerate(digits):
             k = evk.digits[j]
-            t0 = poly.mul(d, k[0][rows], mods)
-            t1 = poly.mul(d, k[1][rows], mods)
-            acc0 = t0 if acc0 is None else poly.add(acc0, t0, mods)
-            acc1 = t1 if acc1 is None else poly.add(acc1, t1, mods)
+            t0 = self.pc.mul(d, k[0][rows], mods)
+            t1 = self.pc.mul(d, k[1][rows], mods)
+            acc0 = t0 if acc0 is None else self.pc.add(acc0, t0, mods)
+            acc1 = t1 if acc1 is None else self.pc.add(acc1, t1, mods)
         return acc0, acc1
 
     def _note_seed_ks(self, level: int, n_ip: int = 1,
@@ -372,13 +371,13 @@ class CKKSContext:
         self._require_same_level(a, b, "multiply")
         lvl = a.level
         mods = self.pc.mods(self.chain(lvl))
-        d0, d1, d2 = tensor_product(a, b, mods)
+        d0, d1, d2 = tensor_product(a, b, mods, self.pc)
         if self.use_engine:
             c0, c1 = self.engine.relin(d0, d1, d2, self.keys.mult_key, lvl)
         else:
             self.counters.relin += 1
             e0, e1 = self.keyswitch_seed(d2, self.keys.mult_key, lvl)
-            c0, c1 = poly.add(d0, e0, mods), poly.add(d1, e1, mods)
+            c0, c1 = self.pc.add(d0, e0, mods), self.pc.add(d1, e1, mods)
         out = Ciphertext(c0, c1, lvl, a.scale * b.scale)
         return self.rescale(out) if rescale else out
 
@@ -390,8 +389,8 @@ class CKKSContext:
         mods = self.pc.mods(self.chain(ct.level))
         two = (mods * 0 + 2).astype(mods.dtype)
         return Ciphertext(
-            poly.mul_scalar(ct.c0, two, mods),
-            poly.mul_scalar(ct.c1, two, mods),
+            self.pc.mul_scalar(ct.c0, two, mods),
+            self.pc.mul_scalar(ct.c1, two, mods),
             ct.level, ct.scale,
         )
 
@@ -408,7 +407,7 @@ class CKKSContext:
         c1r = poly.automorphism(ct.c1, primes, galois, self.pc)
         d0, d1 = self.keyswitch_seed(c1r, evk, lvl)
         return Ciphertext(
-            poly.add(c0r, d0, mods), d1, lvl, ct.scale
+            self.pc.add(c0r, d0, mods), d1, lvl, ct.scale
         )
 
     def rotate(self, ct: Ciphertext, steps: int) -> Ciphertext:
@@ -539,18 +538,18 @@ class CKKSContext:
             c0r = poly.automorphism(ct.c0, base, g, self.pc)
             if pt_ms is not None:
                 pm_ext = self._pmodup(pt_ms[i], lvl)
-                ks0 = poly.mul(ks0, pm_ext, ext_mods)
-                ks1 = poly.mul(ks1, pm_ext, ext_mods)
-                c0r = poly.mul(c0r, pt_ms[i].m[: lvl + 1], base_mods)
-            acc0e = ks0 if acc0e is None else poly.add(acc0e, ks0, ext_mods)
-            acc1e = ks1 if acc1e is None else poly.add(acc1e, ks1, ext_mods)
-            base0 = c0r if base0 is None else poly.add(base0, c0r, base_mods)
+                ks0 = self.pc.mul(ks0, pm_ext, ext_mods)
+                ks1 = self.pc.mul(ks1, pm_ext, ext_mods)
+                c0r = self.pc.mul(c0r, pt_ms[i].m[: lvl + 1], base_mods)
+            acc0e = ks0 if acc0e is None else self.pc.add(acc0e, ks0, ext_mods)
+            acc1e = ks1 if acc1e is None else self.pc.add(acc1e, ks1, ext_mods)
+            base0 = c0r if base0 is None else self.pc.add(base0, c0r, base_mods)
 
         d0 = poly.moddown(acc0e, lvl, self.pc)
         d1 = poly.moddown(acc1e, lvl, self.pc)
         out_scale = ct.scale * (pts[0].scale if pts is not None else 1.0)
         out = Ciphertext(
-            poly.add(base0, d0, base_mods), d1, lvl, out_scale
+            self.pc.add(base0, d0, base_mods), d1, lvl, out_scale
         )
         if pts is not None and rescale:
             out = self.rescale(out)

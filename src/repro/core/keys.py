@@ -108,15 +108,15 @@ class KeyChain:
             a_eval = poly.ntt(jnp.asarray(a_rns), primes, pc)
             e_rns = to_rns(sample_gaussian(self.rng, p.N), primes)
             e_eval = poly.ntt(jnp.asarray(e_rns), primes, pc)
-            b = poly.sub(
-                poly.add(
-                    poly.mul_scalar(
+            b = pc.sub(
+                pc.add(
+                    pc.mul_scalar(
                         s_prime_eval, jnp.asarray(self._gadgets[j]), mods
                     ),
                     e_eval,
                     mods,
                 ),
-                poly.mul(a_eval, self.s_eval, mods),
+                pc.mul(a_eval, self.s_eval, mods),
                 mods,
             )
             digits.append(jnp.stack([b, a_eval]))
@@ -127,7 +127,7 @@ class KeyChain:
     def mult_key(self) -> EvalKey:
         if self._mult_key is None:
             mods = self.pc.mods(self.ext_primes)
-            s2 = poly.mul(self.s_eval, self.s_eval, mods)
+            s2 = self.pc.mul(self.s_eval, self.s_eval, mods)
             self._mult_key = self._gen_evk(s2)
         return self._mult_key
 

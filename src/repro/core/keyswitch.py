@@ -51,7 +51,8 @@ the relin analogue of ``multi_hoisted_rotation_sum`` (ARK-style lazy
 ModDown), driven by ``runtime.lower.MultiRelinStep``.
 
 Backends (``PolyContext.backend``):
-  * ``"jnp"``    — exact uint64 ``(a * b) % q`` ops, batched as above.
+  * ``"jnp"``    — exact uint64 ``(a * b) % q`` ops (``PolyContext.modq``),
+    batched as above.
   * ``"pallas"`` — uint32 Montgomery Pallas kernel suite.  ModUp runs
     the FUSED kernel (``kernels/modup``): one ``pallas_call`` per digit
     executes INTT -> BConv tree-reduce -> NTT with the digit
@@ -73,6 +74,8 @@ and ``tests/test_relin.py``).
 """
 from __future__ import annotations
 
+import copy
+from functools import partial
 from typing import TYPE_CHECKING
 
 import jax
@@ -85,9 +88,9 @@ from repro.core.counters import OpCounters
 from repro.errors import ModulusChainMismatchError
 from repro.kernels.bconv.ops import bconv_kernel
 from repro.kernels.fused_ip.ops import fused_ip_mont
-from repro.kernels.modops import default_interpret, qinv_neg_host
+from repro.kernels.modops import qinv_neg_host
 from repro.kernels.modup.ops import modup_digit
-from repro.kernels.ntt.ops import ntt_fwd, ntt_inv, tables_for
+from repro.kernels.ntt.ops import ntt_fwd, ntt_inv
 
 if TYPE_CHECKING:  # avoid importing keys at runtime (ckks -> keyswitch)
     from repro.core.keys import EvalKey
@@ -109,12 +112,12 @@ def ext_rows(params, level: int) -> np.ndarray:
 def _to_mont_host_rows(arr: np.ndarray, q: np.ndarray) -> np.ndarray:
     """Montgomery-convert (..., l, N) uint64 with per-row moduli (l,).
 
-    Exact object-int arithmetic, vectorized; done once per evk/plaintext
-    and cached by the engine.
+    Exact in uint64 (residues < q < 2^31, so ``x << 32`` < 2^63);
+    done once per evk/plaintext and cached by the engine.
     """
     shape = (1,) * (arr.ndim - 2) + (len(q), 1)
-    qcol = q.astype(object).reshape(shape)
-    return ((arr.astype(object) << 32) % qcol).astype(np.uint32)
+    qcol = q.astype(np.uint64).reshape(shape)
+    return ((arr.astype(np.uint64) << np.uint64(32)) % qcol).astype(np.uint32)
 
 
 class KeyswitchPlan:
@@ -140,9 +143,11 @@ class KeyswitchPlan:
         self.p_tiled = params.p_primes * 2
         self.base_tiled = self.base * 2
 
-        self.base_mods = jnp.asarray(np.array(self.base, dtype=np.uint64))
-        self.ext_mods = jnp.asarray(np.array(self.ext, dtype=np.uint64))
-        self.p_mods = jnp.asarray(np.array(params.p_primes, dtype=np.uint64))
+        # host constants: the TPU reduction derives its per-modulus
+        # Montgomery constants from them on the host (``poly._modq_mont``)
+        self.base_mods = np.array(self.base, dtype=np.uint64)
+        self.ext_mods = np.array(self.ext, dtype=np.uint64)
+        self.p_mods = np.array(params.p_primes, dtype=np.uint64)
 
         # --- ModUp: per-limb scale constants + block-diagonal reduce ---
         qinv = np.zeros(self.l, dtype=np.uint64)
@@ -180,12 +185,18 @@ class KeyswitchPlan:
 
         # --- Pallas backend extras ---
         self.bitrev = np.asarray(pc.rns.bitrev)
-        q32 = np.array(self.ext, dtype=np.uint32).reshape(self.l_ext, 1)
-        qneg32 = np.array(
-            [qinv_neg_host(q) for q in self.ext], dtype=np.uint32
-        ).reshape(self.l_ext, 1)
-        self.q32 = jnp.asarray(q32)
-        self.qneg32 = jnp.asarray(qneg32)
+        # per-limb modulus pair of the fused IP kernel (SMEM operands)
+        self.q32 = np.array(self.ext, dtype=np.uint32)
+        self.qneg32 = np.array(
+            [qinv_neg_host(q) for q in self.ext], dtype=np.uint32)
+        self.pc = pc
+
+    def bind(self, tables: dict) -> "KeyswitchPlan":
+        """This plan computing with the context's ``tables`` (the traced
+        argument of a jitted plan, see ``PolyContext.bind``)."""
+        plan = copy.copy(self)
+        plan.pc = self.pc.bind(tables)
+        return plan
 
 
 class KeyswitchEngine:
@@ -200,8 +211,8 @@ class KeyswitchEngine:
         self.pc = pc
         self.params = pc.params
         self.backend = pc.backend
-        self.interpret = default_interpret()
-        self.tabs = tables_for(pc.params) if self.backend == "pallas" else None
+        self.interpret = pc.interpret
+        self.tabs = pc.ntt_tables
         self.counters = counters if counters is not None else OpCounters()
         self._plans: dict[int, KeyswitchPlan] = {}
         self._ks_fns: dict[int, object] = {}
@@ -350,19 +361,22 @@ class KeyswitchEngine:
         """Batched forward NTT, core (natural) eval order in/out."""
         if self.backend == "pallas":
             y = ntt_fwd(x.astype(jnp.uint32), primes, self.tabs,
-                        interpret=self.interpret)
-            return y[:, plan.bitrev].astype(jnp.uint64)
-        return poly.ntt(x, primes, self.pc)
+                        interpret=self.interpret,
+                        dev=plan.pc.kernel_tables)
+            return poly.fence(y[:, plan.bitrev].astype(jnp.uint64))
+        return poly.ntt(x, primes, plan.pc)
 
     def _intt(self, x, primes, plan: KeyswitchPlan):
         if self.backend == "pallas":
             y = ntt_inv(x[:, plan.bitrev].astype(jnp.uint32), primes,
-                        self.tabs, interpret=self.interpret)
-            return y.astype(jnp.uint64)
-        return poly.intt(x, primes, self.pc)
+                        self.tabs, interpret=self.interpret,
+                        dev=plan.pc.kernel_tables)
+            return poly.fence(y.astype(jnp.uint64))
+        return poly.intt(x, primes, plan.pc)
 
     def _modup(self, a, plan: KeyswitchPlan):
         """(l, N) eval -> (dnum, l_ext, N) eval, all digits at once."""
+        modq = self.pc.modq
         if self.backend == "pallas":
             # ONE fused pallas_call per digit (kernels/modup): INTT ->
             # BConv tree-reduce -> NTT with the digit VMEM-resident
@@ -376,23 +390,24 @@ class KeyswitchEngine:
                 digs.append(modup_digit(
                     x[row : row + len(D)], tuple(D), plan.ext,
                     self.tabs, self.pc.rns, interpret=self.interpret,
+                    dev=plan.pc.kernel_tables,
                 ))
                 row += len(D)
-            conv = jnp.stack(digs)[:, :, plan.bitrev].astype(jnp.uint64)
+            conv = poly.fence(
+                jnp.stack(digs)[:, :, plan.bitrev].astype(jnp.uint64))
         else:
             coeff = self._intt(a, plan.base, plan)
-            t = (coeff * plan.qinv[:, None]) % plan.base_mods[:, None]
+            t = modq(coeff * plan.qinv[:, None], plan.base_mods[:, None])
             td = t[plan.src_idx]                       # (dnum, alpha, N)
             em = plan.ext_mods[None, :, None]
             conv = jnp.zeros(
                 (plan.dnum, plan.l_ext, plan.N), dtype=jnp.uint64
             )
             for i in range(0, plan.alpha, _CHUNK):
-                part = (
+                part = modq(
                     td[:, i : i + _CHUNK, None, :]
-                    * plan.C[:, i : i + _CHUNK, :, None]
-                ) % em[None]
-                conv = (conv + part.sum(axis=1)) % em
+                    * plan.C[:, i : i + _CHUNK, :, None], em[None])
+                conv = modq(conv + part.sum(axis=1), em)
             conv = conv.reshape(plan.dnum * plan.l_ext, plan.N)
             conv = self._ntt(conv, plan.ext_tiled, plan)
             conv = conv.reshape(plan.dnum, plan.l_ext, plan.N)
@@ -406,69 +421,76 @@ class KeyswitchEngine:
                 digits.astype(jnp.uint32), evk, None, plan.q32, plan.qneg32,
                 interpret=self.interpret,
             )
-            return jnp.stack([a0, a1]).astype(jnp.uint64)
+            return poly.fence(jnp.stack([a0, a1]).astype(jnp.uint64))
+        modq = self.pc.modq
         em = plan.ext_mods[None, None, :, None]
-        prod = (digits[:, None] * evk) % em            # (dnum, 2, l_ext, N)
-        return prod.sum(axis=0) % em[0]
+        prod = modq(digits[:, None] * evk, em)        # (dnum, 2, l_ext, N)
+        return modq(prod.sum(axis=0), em[0])
 
     def _moddown2(self, acc, plan: KeyswitchPlan):
         """Batched ModDown of both accumulators: (2, l_ext, N) -> (2, l, N)."""
+        modq = self.pc.modq
         xq, xp = acc[:, : plan.l], acc[:, plan.l :]
         xpc = self._intt(
             xp.reshape(2 * plan.k, plan.N), plan.p_tiled, plan
         )
         bm = plan.base_mods[None, :, None]
         if self.backend == "pallas":
-            conv = jnp.stack([
+            conv = poly.fence(jnp.stack([
                 bconv_kernel(
                     xpc[c * plan.k : (c + 1) * plan.k].astype(jnp.uint32),
                     self.params.p_primes, plan.base, self.pc.rns,
                     interpret=self.interpret,
                 )
                 for c in range(2)
-            ]).astype(jnp.uint64)
+            ]).astype(jnp.uint64))
         else:
             xpc = xpc.reshape(2, plan.k, plan.N)
-            t = (xpc * plan.md_qhat_inv[None, :, None]) % plan.p_mods[None, :, None]
+            t = modq(xpc * plan.md_qhat_inv[None, :, None],
+                     plan.p_mods[None, :, None])
             conv = jnp.zeros((2, plan.l, plan.N), dtype=jnp.uint64)
             for i in range(0, plan.k, _CHUNK):
-                part = (
+                part = modq(
                     t[:, i : i + _CHUNK, None, :]
-                    * plan.md_C[None, i : i + _CHUNK, :, None]
-                ) % bm[:, None]
-                conv = (conv + part.sum(axis=1)) % bm
+                    * plan.md_C[None, i : i + _CHUNK, :, None], bm[:, None])
+                conv = modq(conv + part.sum(axis=1), bm)
         conv = self._ntt(
             conv.reshape(2 * plan.l, plan.N), plan.base_tiled, plan
         ).reshape(2, plan.l, plan.N)
-        diff = (xq + bm - conv) % bm
-        return (diff * plan.pinv[None, :, None]) % bm
+        diff = modq(xq + bm - conv, bm)
+        return modq(diff * plan.pinv[None, :, None], bm)
 
     # ------------------------- jitted entry points ---------------------
+    def _jit(self, level: int, fn):
+        """``jax.jit`` of ``fn(plan, *args)`` over ``level``'s plan.
+
+        The context's tables are an argument of the compiled program,
+        bound onto the plan inside the trace (``PolyContext.bind``)."""
+        plan = self._plan(level)
+        jitted = jax.jit(lambda tables, *args: fn(plan.bind(tables), *args))
+        return partial(jitted, self.pc.tables)
+
     def _ks_fn(self, level: int):
         if level not in self._ks_fns:
-            plan = self._plan(level)
-
-            def fn(a, evk):
+            def fn(plan, a, evk):
                 self._count_trace(("keyswitch", level))
                 digits = self._modup(a, plan)
                 d = self._moddown2(self._ip(digits, evk, plan), plan)
                 return d[0], d[1]
 
-            self._ks_fns[level] = jax.jit(fn)
+            self._ks_fns[level] = self._jit(level, fn)
         return self._ks_fns[level]
 
     def _galois_fn(self, level: int):
         if level not in self._galois_fns:
-            plan = self._plan(level)
-
-            def fn(c0, c1, perm, evk):
+            def fn(plan, c0, c1, perm, evk):
                 self._count_trace(("galois", level))
                 digits = self._modup(c1[:, perm], plan)
                 d = self._moddown2(self._ip(digits, evk, plan), plan)
                 bm = plan.base_mods[:, None]
-                return (c0[:, perm] + d[0]) % bm, d[1]
+                return self.pc.modq(c0[:, perm] + d[0], bm), d[1]
 
-            self._galois_fns[level] = jax.jit(fn)
+            self._galois_fns[level] = self._jit(level, fn)
         return self._galois_fns[level]
 
     def _hoist_core(self, plan: KeyswitchPlan, n_rot: int, with_pt: bool,
@@ -476,6 +498,7 @@ class KeyswitchEngine:
         """Hoisted-rotation-sum body AFTER ModUp: rotate digits, IP,
         accumulate, one batched ModDown.  Shared by the monolithic,
         digits-in and vmap-batched entry points (bit-exact across all)."""
+        modq = self.pc.modq
         # One gather rotates ALL digits for ALL rotations.
         d_rot = jnp.transpose(
             digits[:, :, perms], (2, 0, 1, 3)
@@ -490,34 +513,32 @@ class KeyswitchEngine:
                     plan.q32, plan.qneg32, interpret=self.interpret,
                 )
                 ipr = jnp.stack([a0, a1]).astype(jnp.uint64)
-                acc = ipr if acc is None else (acc + ipr) % em
+                acc = ipr if acc is None else modq(acc + ipr, em)
         else:
-            prod = (d_rot[:, :, None] * evk_all) % em[None, None]
-            ip = prod.sum(axis=1) % em[None]   # (R, 2, l_ext, N)
+            prod = modq(d_rot[:, :, None] * evk_all, em[None, None])
+            ip = modq(prod.sum(axis=1), em[None])       # (R, 2, l_ext, N)
             if with_pt:
-                ip = (ip * pm_ext[:, None]) % em[None]
-            acc = ip.sum(axis=0) % em
+                ip = modq(ip * pm_ext[:, None], em[None])
+            acc = modq(ip.sum(axis=0), em)
         bm = plan.base_mods[None, :, None]
         c0r = jnp.transpose(c0[:, perms], (1, 0, 2))  # (R, l, N)
         if with_pt:
-            c0r = (c0r * pm_base) % bm
-        base0 = c0r.sum(axis=0) % plan.base_mods[:, None]
+            c0r = modq(c0r * pm_base, bm)
+        base0 = modq(c0r.sum(axis=0), plan.base_mods[:, None])
         d = self._moddown2(acc, plan)
-        return (base0 + d[0]) % plan.base_mods[:, None], d[1]
+        return modq(base0 + d[0], plan.base_mods[:, None]), d[1]
 
     def _hoist_fn(self, level: int, n_rot: int, with_pt: bool):
         key = (level, n_rot, with_pt)
         if key not in self._hoist_fns:
-            plan = self._plan(level)
-
-            def fn(c0, c1, perms, evk_all, pm_ext, pm_base, pm_ext_m):
+            def fn(plan, c0, c1, perms, evk_all, pm_ext, pm_base, pm_ext_m):
                 self._count_trace(("hoisted", level, n_rot, with_pt))
                 digits = self._modup(c1, plan)
                 return self._hoist_core(plan, n_rot, with_pt, c0, digits,
                                         perms, evk_all, pm_ext, pm_base,
                                         pm_ext_m)
 
-            self._hoist_fns[key] = jax.jit(fn)
+            self._hoist_fns[key] = self._jit(level, fn)
         return self._hoist_fns[key]
 
     def _hoist_digits_fn(self, level: int, n_rot: int, with_pt: bool):
@@ -525,15 +546,14 @@ class KeyswitchEngine:
         ModUp across sibling blocks anchored on the same ciphertext."""
         key = ("digits", level, n_rot, with_pt)
         if key not in self._hoist_fns:
-            plan = self._plan(level)
-
-            def fn(c0, digits, perms, evk_all, pm_ext, pm_base, pm_ext_m):
+            def fn(plan, c0, digits, perms, evk_all, pm_ext, pm_base,
+                   pm_ext_m):
                 self._count_trace(("hoisted_digits", level, n_rot, with_pt))
                 return self._hoist_core(plan, n_rot, with_pt, c0, digits,
                                         perms, evk_all, pm_ext, pm_base,
                                         pm_ext_m)
 
-            self._hoist_fns[key] = jax.jit(fn)
+            self._hoist_fns[key] = self._jit(level, fn)
         return self._hoist_fns[key]
 
     def _acc_ip_ext(self, plan: KeyswitchPlan, n: int, d_terms, evk_all):
@@ -544,6 +564,7 @@ class KeyswitchEngine:
         mult key) over every term.  The single accumulation body behind
         both merged-ModDown flavors (multi-anchor rotation sums and
         multi-relin closures), on either backend."""
+        modq = self.pc.modq
         em = plan.ext_mods[None, :, None]
         if self.backend == "pallas":
             shared = evk_all.shape[0] == 1
@@ -555,36 +576,35 @@ class KeyswitchEngine:
                     plan.q32, plan.qneg32, interpret=self.interpret,
                 )
                 ipr = jnp.stack([a0, a1]).astype(jnp.uint64)
-                acc = ipr if acc is None else (acc + ipr) % em
+                acc = ipr if acc is None else modq(acc + ipr, em)
             return acc
-        prod = (d_terms[:, :, None] * evk_all) % em[None, None]
-        ip = prod.sum(axis=1) % em[None]       # (n, 2, l_ext, N)
-        return ip.sum(axis=0) % em
+        prod = modq(d_terms[:, :, None] * evk_all, em[None, None])
+        ip = modq(prod.sum(axis=1), em[None])         # (n, 2, l_ext, N)
+        return modq(ip.sum(axis=0), em)
 
     def _multi_core(self, plan: KeyswitchPlan, n: int, c0s, digits,
                     perms, evk_all):
         """Multi-anchor accumulation body: rotate each anchor's digits
         by ITS perm, IP against ITS evk, accumulate every term in the
         extended basis, and close with ONE batched ModDown."""
+        modq = self.pc.modq
         d_rot = jax.vmap(lambda d, p: d[:, :, p])(digits, perms)
         acc = self._acc_ip_ext(plan, n, d_rot, evk_all)
         bm = plan.base_mods[:, None]
         c0r = jax.vmap(lambda c, p: c[:, p])(c0s, perms)   # (n, l, N)
-        base0 = c0r.sum(axis=0) % bm
+        base0 = modq(c0r.sum(axis=0), bm)
         d = self._moddown2(acc, plan)
-        return (base0 + d[0]) % bm, d[1]
+        return modq(base0 + d[0], bm), d[1]
 
     def _multi_fn(self, level: int, n: int):
         key = ("multi", level, n)
         if key not in self._hoist_fns:
-            plan = self._plan(level)
-
-            def fn(c0s, digits, perms, evk_all):
+            def fn(plan, c0s, digits, perms, evk_all):
                 self._count_trace(("multi_hoisted", level, n))
                 return self._multi_core(plan, n, c0s, digits, perms,
                                         evk_all)
 
-            self._hoist_fns[key] = jax.jit(fn)
+            self._hoist_fns[key] = self._jit(level, fn)
         return self._hoist_fns[key]
 
     # ------------------------- relinearization -------------------------
@@ -598,19 +618,17 @@ class KeyswitchEngine:
         """IP + ModDown of relin digits, folded into (d0, d1)."""
         d = self._moddown2(self._ip(digits, evk, plan), plan)
         bm = plan.base_mods[:, None]
-        return (d0 + d[0]) % bm, (d1 + d[1]) % bm
+        return self.pc.modq(d0 + d[0], bm), self.pc.modq(d1 + d[1], bm)
 
     def _relin_fn(self, level: int, digits_in: bool):
         key = ("relin", level, digits_in)
         if key not in self._hoist_fns:
-            plan = self._plan(level)
-
-            def fn(d0, d1, x, evk):
+            def fn(plan, d0, d1, x, evk):
                 self._count_trace(("relin", level, digits_in))
                 digits = x if digits_in else self._modup(x, plan)
                 return self._relin_core(plan, d0, d1, digits, evk)
 
-            self._hoist_fns[key] = jax.jit(fn)
+            self._hoist_fns[key] = self._jit(level, fn)
         return self._hoist_fns[key]
 
     def _multi_relin_core(self, plan: KeyswitchPlan, n: int, d0s, d1s,
@@ -619,168 +637,131 @@ class KeyswitchEngine:
         SHARED mult key) accumulates in the extended basis; ONE batched
         ModDown closes the sum — the relin analogue of ``_multi_core``
         (ARK-style lazy/deferred ModDown over summed relin outputs)."""
+        modq = self.pc.modq
         acc = self._acc_ip_ext(plan, n, digits, evk[None])
         bm = plan.base_mods[:, None]
-        base0 = d0s.sum(axis=0) % bm
-        base1 = d1s.sum(axis=0) % bm
+        base0 = modq(d0s.sum(axis=0), bm)
+        base1 = modq(d1s.sum(axis=0), bm)
         d = self._moddown2(acc, plan)
-        return (base0 + d[0]) % bm, (base1 + d[1]) % bm
+        return modq(base0 + d[0], bm), modq(base1 + d[1], bm)
 
     def _multi_relin_fn(self, level: int, n: int):
         key = ("multi_relin", level, n)
         if key not in self._hoist_fns:
-            plan = self._plan(level)
-
-            def fn(d0s, d1s, digits, evk):
+            def fn(plan, d0s, d1s, digits, evk):
                 self._count_trace(("multi_relin", level, n))
                 return self._multi_relin_core(plan, n, d0s, d1s, digits,
                                               evk)
 
-            self._hoist_fns[key] = jax.jit(fn)
+            self._hoist_fns[key] = self._jit(level, fn)
         return self._hoist_fns[key]
 
     def _modup_fn(self, level: int):
         if level not in self._modup_fns:
-            plan = self._plan(level)
-
-            def fn(a):
+            def fn(plan, a):
                 self._count_trace(("modup", level))
                 return self._modup(a, plan)
 
-            self._modup_fns[level] = jax.jit(fn)
+            self._modup_fns[level] = self._jit(level, fn)
         return self._modup_fns[level]
 
     # ------------------------- batched (vmap) fns ----------------------
-    def _batched_fn(self, key: tuple, make):
+    def _batched_fn(self, key: tuple, fn):
         """jit(vmap) plan cache: one trace per (op, level, shape) plan —
         re-dispatch at the same batch shape is a cache hit (asserted by
-        ``trace_counts``, which only increments while tracing)."""
+        ``trace_counts``, which only increments while tracing).  ``key[1]``
+        is the plan's level."""
         if key not in self._batch_fns:
-            self._batch_fns[key] = jax.jit(make())
+            self._batch_fns[key] = self._jit(key[1], fn)
         return self._batch_fns[key]
 
     def _ks_batched_fn(self, level: int):
-        plan = self._plan(level)
+        def fn(plan, ab, evk):
+            self._count_trace(("keyswitch_b", level))
 
-        def make():
-            def fn(ab, evk):
-                self._count_trace(("keyswitch_b", level))
+            def one(a):
+                digits = self._modup(a, plan)
+                d = self._moddown2(self._ip(digits, evk, plan), plan)
+                return d[0], d[1]
 
-                def one(a):
-                    digits = self._modup(a, plan)
-                    d = self._moddown2(self._ip(digits, evk, plan), plan)
-                    return d[0], d[1]
+            return jax.vmap(one)(ab)
 
-                return jax.vmap(one)(ab)
-
-            return fn
-
-        return self._batched_fn(("keyswitch_b", level), make)
+        return self._batched_fn(("keyswitch_b", level), fn)
 
     def _galois_batched_fn(self, level: int):
-        plan = self._plan(level)
+        def fn(plan, c0b, c1b, perm, evk):
+            self._count_trace(("galois_b", level))
+            bm = plan.base_mods[:, None]
 
-        def make():
-            def fn(c0b, c1b, perm, evk):
-                self._count_trace(("galois_b", level))
-                bm = plan.base_mods[:, None]
+            def one(c0, c1):
+                digits = self._modup(c1[:, perm], plan)
+                d = self._moddown2(self._ip(digits, evk, plan), plan)
+                return self.pc.modq(c0[:, perm] + d[0], bm), d[1]
 
-                def one(c0, c1):
-                    digits = self._modup(c1[:, perm], plan)
-                    d = self._moddown2(self._ip(digits, evk, plan), plan)
-                    return (c0[:, perm] + d[0]) % bm, d[1]
+            return jax.vmap(one)(c0b, c1b)
 
-                return jax.vmap(one)(c0b, c1b)
-
-            return fn
-
-        return self._batched_fn(("galois_b", level), make)
+        return self._batched_fn(("galois_b", level), fn)
 
     def _hoist_batched_fn(self, level: int, n_rot: int, with_pt: bool,
                           digits_in: bool):
-        plan = self._plan(level)
+        def fn(plan, c0b, xb, perms, evk_all, pm_ext, pm_base, pm_ext_m):
+            self._count_trace(
+                ("hoisted_b", level, n_rot, with_pt, digits_in))
 
-        def make():
-            def fn(c0b, xb, perms, evk_all, pm_ext, pm_base, pm_ext_m):
-                self._count_trace(
-                    ("hoisted_b", level, n_rot, with_pt, digits_in))
+            def one(c0, x):
+                digits = x if digits_in else self._modup(x, plan)
+                return self._hoist_core(
+                    plan, n_rot, with_pt, c0, digits, perms, evk_all,
+                    pm_ext, pm_base, pm_ext_m,
+                )
 
-                def one(c0, x):
-                    digits = x if digits_in else self._modup(x, plan)
-                    return self._hoist_core(
-                        plan, n_rot, with_pt, c0, digits, perms, evk_all,
-                        pm_ext, pm_base, pm_ext_m,
-                    )
-
-                return jax.vmap(one)(c0b, xb)
-
-            return fn
+            return jax.vmap(one)(c0b, xb)
 
         return self._batched_fn(
-            ("hoisted_b", level, n_rot, with_pt, digits_in), make)
+            ("hoisted_b", level, n_rot, with_pt, digits_in), fn)
 
     def _multi_batched_fn(self, level: int, n: int):
-        plan = self._plan(level)
+        def fn(plan, c0s, digits, perms, evk_all):
+            self._count_trace(("multi_hoisted_b", level, n))
 
-        def make():
-            def fn(c0s, digits, perms, evk_all):
-                self._count_trace(("multi_hoisted_b", level, n))
+            def one(c0s_1, digits_1):
+                return self._multi_core(plan, n, c0s_1, digits_1,
+                                        perms, evk_all)
 
-                def one(c0s_1, digits_1):
-                    return self._multi_core(plan, n, c0s_1, digits_1,
-                                            perms, evk_all)
+            return jax.vmap(one, in_axes=(1, 1))(c0s, digits)
 
-                return jax.vmap(one, in_axes=(1, 1))(c0s, digits)
-
-            return fn
-
-        return self._batched_fn(("multi_hoisted_b", level, n), make)
+        return self._batched_fn(("multi_hoisted_b", level, n), fn)
 
     def _relin_batched_fn(self, level: int, digits_in: bool):
-        plan = self._plan(level)
+        def fn(plan, d0b, d1b, xb, evk):
+            self._count_trace(("relin_b", level, digits_in))
 
-        def make():
-            def fn(d0b, d1b, xb, evk):
-                self._count_trace(("relin_b", level, digits_in))
+            def one(d0, d1, x):
+                digits = x if digits_in else self._modup(x, plan)
+                return self._relin_core(plan, d0, d1, digits, evk)
 
-                def one(d0, d1, x):
-                    digits = x if digits_in else self._modup(x, plan)
-                    return self._relin_core(plan, d0, d1, digits, evk)
+            return jax.vmap(one)(d0b, d1b, xb)
 
-                return jax.vmap(one)(d0b, d1b, xb)
-
-            return fn
-
-        return self._batched_fn(("relin_b", level, digits_in), make)
+        return self._batched_fn(("relin_b", level, digits_in), fn)
 
     def _multi_relin_batched_fn(self, level: int, n: int):
-        plan = self._plan(level)
+        def fn(plan, d0s, d1s, digits, evk):
+            self._count_trace(("multi_relin_b", level, n))
 
-        def make():
-            def fn(d0s, d1s, digits, evk):
-                self._count_trace(("multi_relin_b", level, n))
+            def one(d0s_1, d1s_1, digits_1):
+                return self._multi_relin_core(plan, n, d0s_1, d1s_1,
+                                              digits_1, evk)
 
-                def one(d0s_1, d1s_1, digits_1):
-                    return self._multi_relin_core(plan, n, d0s_1, d1s_1,
-                                                  digits_1, evk)
+            return jax.vmap(one, in_axes=(1, 1, 1))(d0s, d1s, digits)
 
-                return jax.vmap(one, in_axes=(1, 1, 1))(d0s, d1s, digits)
-
-            return fn
-
-        return self._batched_fn(("multi_relin_b", level, n), make)
+        return self._batched_fn(("multi_relin_b", level, n), fn)
 
     def _modup_batched_fn(self, level: int):
-        plan = self._plan(level)
+        def fn(plan, ab):
+            self._count_trace(("modup_b", level))
+            return jax.vmap(lambda a: self._modup(a, plan))(ab)
 
-        def make():
-            def fn(ab):
-                self._count_trace(("modup_b", level))
-                return jax.vmap(lambda a: self._modup(a, plan))(ab)
-
-            return fn
-
-        return self._batched_fn(("modup_b", level), make)
+        return self._batched_fn(("modup_b", level), fn)
 
     # ------------------------- public API ------------------------------
     def keyswitch(self, a, evk: EvalKey, level: int):

@@ -7,6 +7,8 @@ from __future__ import annotations
 
 from functools import lru_cache
 
+import numpy as np
+
 # Deterministic Miller-Rabin witness set, valid for all n < 3.3e24.
 _MR_WITNESSES = (2, 3, 5, 7, 11, 13, 17, 19, 23, 29, 31, 37)
 
@@ -90,4 +92,8 @@ def _factorize(n: int) -> set[int]:
 
 def bit_reverse_indices(n: int) -> list[int]:
     bits = n.bit_length() - 1
-    return [int(format(i, f"0{bits}b")[::-1], 2) if bits else 0 for i in range(n)]
+    idx = np.arange(n, dtype=np.int64)
+    rev = np.zeros(n, dtype=np.int64)
+    for b in range(bits):
+        rev |= ((idx >> b) & 1) << (bits - 1 - b)
+    return rev.tolist()

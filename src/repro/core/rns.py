@@ -28,32 +28,35 @@ class PrimeTables:
         self.omega = omega
         self.n_inv = nt.modinv(n, p)
 
-        idx = np.arange(n, dtype=object)
-        self.psi_pows = np.array(
-            [pow(psi, int(i), p) for i in idx], dtype=np.uint64
-        )
+        self.psi_pows = _pow_table(psi, n, p)
         psi_inv = nt.modinv(psi, p)
-        self.psi_inv_pows = np.array(
-            [pow(psi_inv, int(i), p) for i in idx], dtype=np.uint64
-        )
+        self.psi_inv_pows = _pow_table(psi_inv, n, p)
         self.bitrev = np.array(nt.bit_reverse_indices(n), dtype=np.int64)
 
         omega_inv = nt.modinv(omega, p)
         # Stage s (s = 0..logn-1) has 2^s twiddles w^(n >> (s+1) * j).
         self.stage_tw = [
-            np.array(
-                [pow(omega, (n >> (s + 1)) * j, p) for j in range(1 << s)],
-                dtype=np.uint64,
-            )
+            _pow_table(pow(omega, n >> (s + 1), p), 1 << s, p)
             for s in range(logn)
         ]
         self.stage_tw_inv = [
-            np.array(
-                [pow(omega_inv, (n >> (s + 1)) * j, p) for j in range(1 << s)],
-                dtype=np.uint64,
-            )
+            _pow_table(pow(omega_inv, n >> (s + 1), p), 1 << s, p)
             for s in range(logn)
         ]
+
+
+def _pow_table(base: int, count: int, p: int) -> np.ndarray:
+    """[base^i mod p for i < count] as uint64, exact.
+
+    Two-level table: base^(a*B + b) = (base^B)^a * base^b, with B ~
+    sqrt(count) Python pows per level and one vectorized uint64 outer
+    product (p < 2^31, so every product fits)."""
+    width = 1 << ((count.bit_length() + 1) // 2)
+    lo = np.array([pow(base, b, p) for b in range(width)], dtype=np.uint64)
+    step = pow(base, width, p)
+    hi = np.array([pow(step, a, p) for a in range(-(-count // width))],
+                  dtype=np.uint64)
+    return ((hi[:, None] * lo[None, :]) % np.uint64(p)).reshape(-1)[:count]
 
 
 def ntt_ref(a: np.ndarray, t: PrimeTables) -> np.ndarray:
@@ -85,6 +88,13 @@ def intt_ref(a: np.ndarray, t: PrimeTables) -> np.ndarray:
     x = x.reshape(n)
     x = (x * np.uint64(t.n_inv)) % p
     return (x * t.psi_inv_pows) % p
+
+
+@lru_cache(maxsize=8)
+def rns_context(params: CKKSParams) -> "RNSContext":
+    """The (read-only) tables of ``params``, built once per process and
+    shared by every ``PolyContext`` and kernel table set."""
+    return RNSContext(params)
 
 
 class RNSContext:

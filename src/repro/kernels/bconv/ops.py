@@ -3,6 +3,7 @@ from __future__ import annotations
 
 from functools import lru_cache
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.custom_batching import custom_vmap
@@ -10,7 +11,9 @@ from jax.custom_batching import custom_vmap
 from repro.core.rns import RNSContext
 from repro.kernels.bconv.bconv import bconv_pallas
 from repro.kernels.bconv import ref as _ref
-from repro.kernels.modops import default_interpret, qinv_neg_host, to_mont_host
+from repro.kernels.modops import (
+    default_interpret, lane_layout, qinv_neg_host, to_mont_host,
+)
 
 
 class BConvKernelConsts:
@@ -20,15 +23,15 @@ class BConvKernelConsts:
         ls, ld = len(src), len(dst)
         self.qhat_inv = qhat_inv
         self.qhat_mod = qhat_mod
-        self.src_q = np.array(src, dtype=np.uint32).reshape(ls, 1)
-        self.dst_q = np.array(dst, dtype=np.uint32).reshape(ld, 1)
+        self.src_q = np.array(src, dtype=np.uint32)
+        self.dst_q = np.array(dst, dtype=np.uint32)
         self.src_qneg = np.array(
             [qinv_neg_host(q) for q in src], dtype=np.uint32
-        ).reshape(ls, 1)
+        )
         self.dst_qneg = np.array(
             [qinv_neg_host(q) for q in dst], dtype=np.uint32
-        ).reshape(ld, 1)
-        self.qhat_inv_mont = np.stack(
+        )
+        self.qhat_inv_mont = np.concatenate(
             [to_mont_host(np.array([qhat_inv[i]]), src[i]) for i in range(ls)]
         )
         self.qhat_mod_mont = np.stack(
@@ -71,7 +74,8 @@ def _bconv_dispatch(rns_id, src, dst, block, interpret):
     def dispatch(x):
         n = x.shape[-1]
         y = bconv_pallas(
-            x.reshape((-1, n)), *consts, block=block, interpret=interpret,
+            x.reshape((-1,) + lane_layout(n)), *consts, block=block,
+            interpret=interpret,
         )
         return y.reshape(x.shape[:-2] + (ld, n))
 
@@ -82,7 +86,9 @@ def _bconv_dispatch(rns_id, src, dst, block, interpret):
         del axis_size, in_batched  # batch axis is at the front: fold it
         return dispatch(x), True
 
-    return fn
+    # jitted: every call with the same operand shapes, in any program,
+    # reuses one traced kernel (tracing them dominated plan lowering)
+    return jax.jit(fn)
 
 
 def bconv_kernel(x, src, dst, rns: RNSContext, block: int = 0,
@@ -101,6 +107,6 @@ def bconv_oracle(x, src, dst, rns: RNSContext):
     _RNS_REGISTRY[id(rns)] = rns
     c = _consts(id(rns), tuple(src), tuple(dst))
     return _ref.bconv_ref(
-        x, jnp.asarray(c.qhat_inv), jnp.asarray(c.src_q.reshape(-1)),
-        jnp.asarray(c.qhat_mod), jnp.asarray(c.dst_q.reshape(-1)),
+        x, jnp.asarray(c.qhat_inv), jnp.asarray(c.src_q),
+        jnp.asarray(c.qhat_mod), jnp.asarray(c.dst_q),
     )

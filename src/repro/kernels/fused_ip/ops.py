@@ -3,13 +3,16 @@ from __future__ import annotations
 
 from functools import lru_cache
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.custom_batching import custom_vmap
 
 from repro.kernels.fused_ip.fused_ip import fused_ip_pallas
 from repro.kernels.fused_ip import ref as _ref
-from repro.kernels.modops import default_interpret, qinv_neg_host, to_mont_host
+from repro.kernels.modops import (
+    default_interpret, lane_layout, qinv_neg_host, to_mont_host,
+)
 
 
 def _mont(arr: np.ndarray, q: np.ndarray) -> np.ndarray:
@@ -38,10 +41,13 @@ def _ip_dispatch(with_pt: bool, interpret: bool):
         n = digits.shape[-1]
         dnum = digits.shape[-3]
         lead = digits.shape[:-3]
+        lay = lane_layout(n)
         d = digits.reshape((-1,) + digits.shape[-3:])      # (B, dnum, l, n)
-        d = jnp.moveaxis(d, 0, 1).reshape(dnum, -1, n)     # (dnum, B*l, n)
+        d = jnp.moveaxis(d, 0, 1).reshape((dnum, -1) + lay)  # (dnum, B*l, ..)
         a0, a1 = fused_ip_pallas(
-            d, evk, pt if with_pt else None, q, qneg, interpret=interpret,
+            d, evk.reshape(evk.shape[:-1] + lay),
+            pt.reshape((l,) + lay) if with_pt else None, q, qneg,
+            interpret=interpret,
         )
         return a0.reshape(lead + (l, n)), a1.reshape(lead + (l, n))
 
@@ -56,7 +62,9 @@ def _ip_dispatch(with_pt: bool, interpret: bool):
                 "plaintext/moduli are per-plan constants")
         return dispatch(digits, evk, pt, q, qneg), (True, True)
 
-    return fn
+    # jitted: every call with the same operand shapes, in any program,
+    # reuses one traced kernel (tracing them dominated plan lowering)
+    return jax.jit(fn)
 
 
 def fused_ip_mont(digits, evk_mont, pt_mont, q, qneg,
@@ -65,13 +73,11 @@ def fused_ip_mont(digits, evk_mont, pt_mont, q, qneg,
     (stored pre-converted, e.g. by the keyswitch engine's per-context
     cache); digits stay normal-form, shape (..., dnum, l, N) — leading
     batch dims (or a ``jax.vmap`` axis) are folded into the kernel grid.
-    q/qneg: (l, 1) uint32."""
+    q/qneg: (l,) uint32.  ``pt_mont=None`` runs the kernel variant
+    without a plaintext operand."""
     if interpret is None:
         interpret = default_interpret()
     with_pt = pt_mont is not None
-    if pt_mont is None:
-        pt_mont = jnp.zeros((q.shape[0], digits.shape[-1]),
-                            dtype=jnp.uint32)
     return _ip_dispatch(with_pt, bool(interpret))(
         digits, evk_mont, pt_mont, q, qneg
     )
@@ -82,7 +88,6 @@ def fused_ip_kernel(digits, evk, pt, q, interpret: bool | None = None):
     real deployment evk/pt are stored pre-converted — see
     ``fused_ip_mont``)."""
     qv = np.asarray(q, dtype=np.uint32)
-    l = qv.shape[0]
     evk_m = _mont(np.asarray(evk), qv)
     pt_m = _mont(np.asarray(pt)[None], qv)[0] if pt is not None else None
     qneg = np.array([qinv_neg_host(int(x)) for x in qv], dtype=np.uint32)
@@ -90,8 +95,8 @@ def fused_ip_kernel(digits, evk, pt, q, interpret: bool | None = None):
         jnp.asarray(np.asarray(digits, dtype=np.uint32)),
         jnp.asarray(evk_m),
         jnp.asarray(pt_m) if pt_m is not None else None,
-        jnp.asarray(qv.reshape(l, 1)),
-        jnp.asarray(qneg.reshape(l, 1)),
+        jnp.asarray(qv),
+        jnp.asarray(qneg),
         interpret=interpret,
     )
 

@@ -65,6 +65,54 @@ def sub_mod(a, b, q):
     return jnp.where(a >= b, a - b, a + q - b)
 
 
+# ------------------------------ TPU layout ------------------------------
+
+LANES = 128          # vreg lane count: the minor dim of every kernel block
+
+
+def lane_layout(n: int) -> tuple[int, int]:
+    """(rows, lanes) view of one N-coefficient limb.
+
+    Kernels see a limb as a lane-dense ``(N/128, 128)`` block so that the
+    last two block dims are (8, 128)-tileable (or whole, for N < 1024) —
+    a ``(1, N)`` row block is refused by the TPU lowering.  Rings below
+    128 coefficients fall back to one ``(1, N)`` row (whole dims)."""
+    lanes = min(n, LANES)
+    return n // lanes, lanes
+
+
+def block_spec(shape, index_map, **kw):
+    """``pl.BlockSpec`` whose index map yields int32 block indices.
+
+    The package runs with ``jax_enable_x64`` on (``repro.core``), under
+    which a Python-int block index lowers as i64, which Mosaic refuses.
+    Index maps built here may return Python ints; arithmetic on the grid
+    indices must use int32 operands (``np.int32(l)``, not ``l``)."""
+    from jax.experimental import pallas as pl
+
+    def imap(*grid):
+        return tuple(np.int32(v) if isinstance(v, int) else v
+                     for v in index_map(*grid))
+
+    return pl.BlockSpec(shape, imap, **kw)
+
+
+def smem_spec(ndim: int = 1):
+    """Whole-array SMEM operand (per-limb scalars, indexed in-kernel by
+    ``program_id``), with an int32 index map (see ``block_spec``)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return block_spec(None, lambda *grid: (0,) * ndim,
+                      memory_space=pltpu.SMEM)
+
+
+def vmem_limit(block_bytes: int) -> int:
+    """Scoped-VMEM request for a kernel whose pipelined blocks and
+    scratch total ``block_bytes``: double the blocks plus 16 MiB for
+    body temporaries, capped well below v5e's 128 MiB of VMEM."""
+    return int(min(2 * block_bytes + (16 << 20), 100 << 20))
+
+
 # ----------------------- host-side constant helpers ----------------------
 
 def default_interpret() -> bool:
@@ -80,5 +128,7 @@ def qinv_neg_host(q: int) -> np.uint32:
 
 
 def to_mont_host(x: np.ndarray, q: int) -> np.ndarray:
-    """Convert constants to Montgomery form on the host (exact ints)."""
-    return ((x.astype(object) * (1 << 32)) % q).astype(np.uint32)
+    """Convert constants to Montgomery form on the host: exact in uint64
+    for residues x < q < 2^31 (``x << 32`` < 2^63)."""
+    x = np.asarray(x).astype(np.uint64)
+    return ((x << np.uint64(32)) % np.uint64(q)).astype(np.uint32)

@@ -3,65 +3,72 @@ from __future__ import annotations
 
 from functools import lru_cache
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.custom_batching import custom_vmap
 
 from repro.core.rns import RNSContext
-from repro.kernels.modops import default_interpret, to_mont_host
+from repro.kernels.modops import (
+    default_interpret, lane_layout, mont_mul, to_mont_host,
+)
 from repro.kernels.modup import ref as _ref
 from repro.kernels.modup.modup import modup_pallas
-from repro.kernels.ntt.ops import NTTKernelTables
+from repro.kernels.ntt.ops import KernelTables, NTTKernelTables
 
 
 class ModUpDigitConsts:
-    """Per-(source digit, destination basis) kernel tables.
+    """Per-(source digit, destination basis) constants.
 
     The BConv per-limb scale ``qhat_inv_i`` is folded into the INTT
-    post-twist here, on the host, in exact object-int arithmetic — the
-    kernel then needs no scale pass at all.  Normal-form copies feed the
-    uint64 oracle."""
+    post-twist: the kernel then needs no scale pass at all.  The kernel's
+    scaled table is derived on the device from the context's NTT tables
+    (``scaled_twist``); the normal-form copy here, exact in uint64
+    (residues < 2^31, so products fit), feeds the oracle."""
 
     def __init__(self, rns: RNSContext, tabs: NTTKernelTables,
                  src: tuple[int, ...], dst: tuple[int, ...]):
         qhat_inv, qhat_mod = rns.bconv_consts(tuple(src), tuple(dst))
         rs = tabs.rows(tuple(src))
         rd = tabs.rows(tuple(dst))
-        ls, ld = len(src), len(dst)
-        n = 1 << tabs.logn
-
-        scaled = np.empty((ls, n), dtype=np.uint64)
-        for i in range(ls):
-            q = int(src[i])
-            scaled[i] = (
-                tabs.twist_i[rs[i]].astype(object) * int(qhat_inv[i]) % q
-            ).astype(np.uint64)
-        self.twist_i_scaled = scaled
-        self.twist_i_scaled_m = np.stack(
-            [to_mont_host(scaled[i], int(src[i])) for i in range(ls)]
-        )
-        self.tw_i_m = tabs.tw_i_m[rs]
-        self.src_q = tabs.q[rs]
-        self.src_qneg = tabs.qinv[rs]
+        ld = len(dst)
+        self.src_rows = tuple(int(i) for i in rs)
+        self.dst_rows = tuple(int(i) for i in rd)
+        self.qhat_inv_mont = np.array(             # (ls,), Montgomery
+            [int(to_mont_host(np.array([qhat_inv[i]]), int(q))[0])
+             for i, q in enumerate(src)], dtype=np.uint32)
+        self.twist_i_scaled = (tabs.twist_i[rs]
+                               * qhat_inv.astype(np.uint64)[:, None]
+                               % tabs.q[rs, None].astype(np.uint64))
         self.qhat_mod = qhat_mod
-        self.c_mont = np.stack([
+        self.c_mont = np.stack([              # (ls, ld), SMEM
             np.array(
                 [int(to_mont_host(np.array([qhat_mod[i, j]]),
                                   int(dst[j]))[0])
                  for j in range(ld)],
                 dtype=np.uint32,
             )
-            for i in range(ls)
+            for i in range(len(src))
         ])
-        self.twist_f_m = tabs.twist_f_m[rd]
-        self.tw_f_m = tabs.tw_f_m[rd]
-        self.dst_q = tabs.q[rd]
-        self.dst_qneg = tabs.qinv[rd]
         # normal-form tables for the oracle
+        self.src_q = tabs.q[rs]
+        self.dst_q = tabs.q[rd]
         self.tw_i = tabs.tw_i[rs]
         self.twist_f = tabs.twist_f[rd]
         self.tw_f = tabs.tw_f[rd]
         self.logn = tabs.logn
+
+    def kernel_tables(self, dev: KernelTables) -> tuple:
+        """The kernel's table operands, taken from the device NTT tables
+        inside the caller's trace: (twist_i_scaled, tw_i, src_q,
+        src_qneg, c_mont, twist_f, tw_f, dst_q, dst_qneg)."""
+        twist_i, tw_i, sq, sqn = dev.take(self.src_rows, inverse=True)
+        col = (slice(None), None, None)
+        scaled = mont_mul(twist_i, jnp.asarray(self.qhat_inv_mont)[col],
+                          sq[col], sqn[col])
+        twist_f, tw_f, dq, dqn = dev.take(self.dst_rows, inverse=False)
+        return (scaled, tw_i, sq, sqn, jnp.asarray(self.c_mont),
+                twist_f, tw_f, dq, dqn)
 
 
 _REGISTRY: dict[tuple, tuple] = {}
@@ -80,52 +87,52 @@ def _consts(reg_key, src, dst) -> ModUpDigitConsts:
 
 
 @lru_cache(maxsize=None)
-def _dispatch(reg_key, src, dst, interpret):
+def _dispatch(logn: int, ld: int, interpret: bool):
     """Rank-polymorphic dispatch + ``custom_vmap`` rule, cached so every
-    trace of the same (digit, basis, backend) reuses ONE callable.
+    trace of the same (ring, basis width, backend) reuses ONE callable.
 
     The dispatch flattens any leading batch dims into the kernel's grid
     axis (batch-major rows) — zero extra materialization — so the vmap
-    rule simply re-invokes it on the batched operand."""
-    c = _consts(reg_key, src, dst)
-    ld = len(dst)
-    # numpy (NOT jnp) constants: the closure is cached across traces, so
-    # captured values must never be tracers — numpy lifts into each
-    # trace as a fresh constant.
-    tables = (
-        c.twist_i_scaled_m, c.tw_i_m, c.src_q, c.src_qneg, c.c_mont,
-        c.twist_f_m, c.tw_f_m, c.dst_q, c.dst_qneg,
-    )
-    logn = c.logn
+    rule simply re-invokes it on the batched operand.  The tables are
+    operands, never captured constants; only ``x`` may be batched."""
+    layout = lane_layout(1 << logn)
 
-    def dispatch(x):
-        n = x.shape[-1]
+    def dispatch(x, *tables):
         y = modup_pallas(
-            x.reshape((-1, n)), *tables, logn=logn, interpret=interpret
+            x.reshape((-1,) + layout), *tables, logn=logn,
+            interpret=interpret,
         )
-        return y.reshape(x.shape[:-2] + (ld, n))
+        return y.reshape(x.shape[:-2] + (ld, x.shape[-1]))
 
     fn = custom_vmap(dispatch)
 
     @fn.def_vmap
-    def _rule(axis_size, in_batched, x):
-        del axis_size, in_batched  # batch axis is at the front: fold it
-        return dispatch(x), True
+    def _rule(axis_size, in_batched, x, *tables):
+        del axis_size  # batch axis is at the front: fold it
+        if any(in_batched[1:]):
+            raise NotImplementedError("modup: only x may be vmapped")
+        return dispatch(x, *tables), True
 
-    return fn
+    # jitted: every call with the same operand shapes, in any program,
+    # reuses one traced kernel (tracing them dominated plan lowering)
+    return jax.jit(fn)
 
 
 def modup_digit(x, src, dst, tabs: NTTKernelTables, rns: RNSContext,
-                interpret: bool | None = None):
+                interpret: bool | None = None,
+                dev: KernelTables | None = None):
     """(..., ls, N) uint32 bit-reversed eval -> (..., ld, N) bit-reversed
     eval: ONE fused pallas_call (INTT -> scaled tree-reduce -> NTT) per
-    digit, VMEM-resident across all three phases.  ``jax.vmap``-safe."""
+    digit, VMEM-resident across all three phases.  ``dev``: the device
+    NTT tables (``tabs.device()``); a jitted caller passes them in as an
+    argument.  ``jax.vmap``-safe."""
     if interpret is None:
         interpret = default_interpret()
-    key = _admit(rns, tabs)
-    return _dispatch(key, tuple(src), tuple(dst), bool(interpret))(
-        x.astype(jnp.uint32)
-    )
+    if dev is None:
+        dev = tabs.device()
+    c = _consts(_admit(rns, tabs), tuple(src), tuple(dst))
+    return _dispatch(c.logn, len(dst), bool(interpret))(
+        x.astype(jnp.uint32), *c.kernel_tables(dev))
 
 
 def modup_digit_oracle(x, src, dst, tabs: NTTKernelTables,
@@ -135,7 +142,8 @@ def modup_digit_oracle(x, src, dst, tabs: NTTKernelTables,
     c = _consts(key, tuple(src), tuple(dst))
     return _ref.modup_digit_ref(
         x, jnp.asarray(c.twist_i_scaled), jnp.asarray(c.tw_i),
-        jnp.asarray(c.src_q.astype(np.uint64)), jnp.asarray(c.qhat_mod),
+        jnp.asarray(c.src_q[:, None].astype(np.uint64)),
+        jnp.asarray(c.qhat_mod),
         jnp.asarray(c.twist_f), jnp.asarray(c.tw_f),
-        jnp.asarray(c.dst_q.astype(np.uint64)),
+        jnp.asarray(c.dst_q[:, None].astype(np.uint64)),
     )

@@ -53,6 +53,35 @@ from repro.runtime.lower import (
 )
 
 
+def _reads(step, dfg) -> tuple[int, ...]:
+    """Node ids of the values ``step`` reads."""
+    if isinstance(step, HoistedStep):
+        return (step.anchor,)
+    if isinstance(step, MultiHoistedStep):
+        return (tuple(a for a, _ in step.rot_terms)
+                + tuple(step.passthrough))
+    if isinstance(step, RelinStep):
+        return tuple(step.args)
+    if isinstance(step, MultiRelinStep):
+        return (tuple(n for _, ab in step.cmults for n in ab)
+                + tuple(step.passthrough))
+    return tuple(dfg.nodes[step.nid].args)
+
+
+def _last_reads(compiled: CompiledProgram) -> list[list[int]]:
+    """Per step, the values it is the last step to read.  The executor
+    drops them (and their ModUp digits) after that step, so a program's
+    live set, not its every intermediate, stays on the device."""
+    last = {}
+    for i, step in enumerate(compiled.steps):
+        for nid in _reads(step, compiled.dfg):
+            last[nid] = i
+    out: list[list[int]] = [[] for _ in compiled.steps]
+    for nid, i in last.items():
+        out[i].append(nid)
+    return out
+
+
 @dataclasses.dataclass
 class ExecResult:
     outputs: dict[str, Ciphertext | list[Ciphertext]]
@@ -169,9 +198,10 @@ class ProgramExecutor:
         # Prefetch the enabled flag once: the disabled hot path is one
         # boolean per step (plus the no-op run span below).
         tracing = obs.TRACER.enabled
+        frees = _last_reads(compiled)
         with obs.span("exec.run", batch=batch,
                       n_steps=len(compiled.steps), validate=validate):
-            for step in compiled.steps:
+            for step, dead in zip(compiled.steps, frees):
                 if tracing:
                     self._exec_step_traced(compiled, step, values, digits,
                                            outputs, inputs, batch, validate)
@@ -184,6 +214,9 @@ class ProgramExecutor:
                     except Exception as err:
                         self._note_validate_failure(compiled, step, err)
                         raise
+                for nid in dead:         # last reader ran: free the value
+                    values.pop(nid, None)
+                    digits.pop(nid, None)
             if validate:
                 for tag, ct in outputs.items():
                     ctx.check_ciphertext(ct, where=f"output '{tag}'")
@@ -361,7 +394,7 @@ class ProgramExecutor:
             out = ctx.multiply(a, b, rescale=False)
         else:
             mods = ctx.pc.mods(ctx.chain(lvl))
-            d0, d1, d2 = tensor_product(a, b, mods)
+            d0, d1, d2 = tensor_product(a, b, mods, ctx.pc)
             key = ctx.keys.mult_key
             if batch:
                 c0, c1 = ctx.engine.relin_batched(d0, d1, d2, key, lvl)
@@ -387,7 +420,7 @@ class ProgramExecutor:
             a, b = values[an], values[bn]
             assert a.level == lvl and b.level == lvl, \
                 "relin operand level drifted from the trace"
-            d0, d1, d2 = tensor_product(a, b, mods)
+            d0, d1, d2 = tensor_product(a, b, mods, ctx.pc)
             d0s.append(d0)
             d1s.append(d1)
             digs.append(ctx.engine.modup_batched(d2, lvl) if batch
@@ -547,10 +580,11 @@ class ProgramExecutor:
             return ctx.rescale(ct)
         lvl = ct.level
         if lvl not in self._rescale_fns:
-            self._rescale_fns[lvl] = jax.jit(jax.vmap(
-                partial(poly.rescale, level=lvl, pc=ctx.pc)
-            ))
-        fn = self._rescale_fns[lvl]
+            # the context's tables are an argument, not constants
+            self._rescale_fns[lvl] = jax.jit(
+                lambda tables, c: jax.vmap(partial(
+                    poly.rescale, level=lvl, pc=ctx.pc.bind(tables)))(c))
+        fn = partial(self._rescale_fns[lvl], ctx.pc.tables)
         q_last = ctx.chain(lvl)[-1]
         return Ciphertext(fn(ct.c0), fn(ct.c1), lvl - 1,
                           ct.scale / q_last)

@@ -142,8 +142,11 @@ class ContinuousBatcher:
         self.max_wait_s = max_wait_s
 
     def _ready(self, reqs: list[Request], now: float, drain: bool) -> bool:
+        # the same float expression as next_flush_time: ``now - arrival
+        # >= max_wait_s`` can stay False at now == arrival + max_wait_s
+        # (rounding), and the idle clock would never advance past it
         return (len(reqs) >= self.max_batch or drain
-                or now - reqs[0].arrival >= self.max_wait_s)
+                or now >= reqs[0].arrival + self.max_wait_s)
 
     def pick(self, queue: RequestQueue, now: float,
              drain: bool = False) -> PackedBatch | None:

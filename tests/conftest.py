@@ -26,3 +26,29 @@ def ctx(small_params):
 @pytest.fixture()
 def rng():
     return np.random.default_rng(42)
+
+
+@pytest.fixture(scope="session")
+def smoke():
+    """``chip_smoke.py`` imported as a module (its phases are unit-tested
+    on the CPU; the script itself only runs on a TPU)."""
+    import importlib.util
+    import pathlib
+
+    path = pathlib.Path(__file__).resolve().parent.parent / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture
+def traced():
+    """obs tracing on for one test (the smoke checks dispatch events)."""
+    from repro import obs
+
+    obs.reset()
+    obs.enable()
+    yield
+    obs.disable()
+    obs.reset()

@@ -87,8 +87,8 @@ def test_mod_raise_exact(boot_ctx, rng):
     def raw_coeffs(ct):
         primes = ctx.chain(ct.level)
         mods = ctx.pc.mods(primes)
-        m_eval = poly.add(
-            ct.c0, poly.mul(ct.c1, ctx.keys.s_eval[: ct.level + 1], mods),
+        m_eval = ctx.pc.add(
+            ct.c0, ctx.pc.mul(ct.c1, ctx.keys.s_eval[: ct.level + 1], mods),
             mods,
         )
         return centered_crt(

@@ -95,6 +95,66 @@ def test_ntt_kernel_consistent_with_core():
         )
 
 
+def test_core_ntt_routes_to_kernel(monkeypatch):
+    """A pallas context running compiled kernels (a TPU) sends
+    ``poly.ntt``/``poly.intt`` through the kernel: same residues as the
+    uint64 stage loop.  Forced here on the CPU, kernel interpreted."""
+    p = CKKSParams(logN=8, L=3, alpha=2, k=2, q_bits=29)
+    pc = poly.PolyContext(p, backend="pallas")
+    primes = p.q_chain(p.L)
+    rng = np.random.default_rng(6)
+    x = jnp.asarray(np.stack(
+        [rng.integers(0, q, p.N, dtype=np.uint64) for q in primes]))
+    exp_f = np.asarray(poly.ntt(x, primes, pc))
+    exp_i = np.asarray(poly.intt(x, primes, pc))
+    monkeypatch.setattr(pc, "kernel_ntt", True)
+    np.testing.assert_array_equal(np.asarray(poly.ntt(x, primes, pc)), exp_f)
+    np.testing.assert_array_equal(np.asarray(poly.intt(x, primes, pc)),
+                                  exp_i)
+
+
+def test_tpu_configuration_matches_cpu_end_to_end():
+    """A pallas context configured as on a TPU (Montgomery ``%``, NTT
+    through the kernel; interpreted here) gives the CPU configuration's
+    residues through encrypt, rotate, multiply and rescale."""
+    from repro.core.ckks import CKKSContext
+
+    p = CKKSParams(logN=8, L=3, alpha=2, k=2, q_bits=29)
+    z = np.random.default_rng(7).uniform(-1, 1, p.num_slots)
+    outs = []
+    for tpu in (False, True):
+        ctx = CKKSContext(p, seed=5, backend="pallas")
+        if tpu:
+            ctx.pc.modq = poly._modq_mont
+            ctx.pc.kernel_ntt = True
+        ct = ctx.encrypt(z)
+        ct = ctx.multiply(ctx.rotate(ct, 3), ct)
+        outs.append(np.asarray(ct.c0))
+        outs.append(np.asarray(ct.c1))
+    np.testing.assert_array_equal(outs[0], outs[2])
+    np.testing.assert_array_equal(outs[1], outs[3])
+
+
+def test_modq_mont_matches_remainder():
+    """The TPU reduction (no 64-bit division) equals ``%`` on the whole
+    uint64 range, for the word sizes in use and a broadcast modulus."""
+    rng = np.random.default_rng(11)
+    qs = [1073479681, 536608769, 268369921, 40961]
+    edge = [0, 1, 2**32 - 1, 2**32, 2**63, 2**64 - 1]
+    for q in qs:
+        x = np.concatenate([rng.integers(0, 2**64 - 1, 50000,
+                                         dtype=np.uint64, endpoint=True),
+                            np.array(edge + [q - 1, q, (q - 1) ** 2],
+                                     dtype=np.uint64)])
+        got = np.asarray(poly._modq_mont(jnp.asarray(x), np.uint64(q)))
+        np.testing.assert_array_equal(got, x % np.uint64(q))
+    qv = np.array(qs, dtype=np.uint64)[:, None]
+    x = rng.integers(0, 2**62, (len(qs), 999), dtype=np.uint64)
+    np.testing.assert_array_equal(
+        np.asarray(poly._modq_mont(jnp.asarray(x), qv)),
+        x % qv)
+
+
 # ------------------------------ BConv ------------------------------------
 
 @pytest.mark.parametrize("logn,ls,ld", [(6, 2, 2), (8, 3, 2), (8, 4, 4)])
@@ -127,8 +187,9 @@ def test_bconv_kernel_vs_core():
 
 
 def test_bconv_kernel_blocked():
-    """Coefficient-blocked grid gives identical results (VMEM tiling)."""
-    p = CKKSParams(logN=8, L=2, alpha=1, k=2, q_bits=29)
+    """Coefficient-blocked grid gives identical results (VMEM tiling).
+    Blocks are whole (8, 128) tiles of lane rows: logN=11 holds two."""
+    p = CKKSParams(logN=11, L=2, alpha=1, k=2, q_bits=29)
     pc = poly.PolyContext(p)
     src, dst = p.q_chain(2), p.p_primes
     rng = np.random.default_rng(10)
@@ -136,7 +197,7 @@ def test_bconv_kernel_blocked():
         np.stack([rng.integers(0, q, p.N, dtype=np.uint32) for q in src])
     )
     full = np.asarray(bconv_kernel(x, src, dst, pc.rns, block=0))
-    blocked = np.asarray(bconv_kernel(x, src, dst, pc.rns, block=64))
+    blocked = np.asarray(bconv_kernel(x, src, dst, pc.rns, block=1024))
     np.testing.assert_array_equal(full, blocked)
 
 

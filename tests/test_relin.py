@@ -92,7 +92,7 @@ def test_relin_digits_interface(relin_ctx):
     b = ctx.encrypt(rng.normal(size=nh))
     lvl = a.level
     mods = ctx.pc.mods(ctx.chain(lvl))
-    d0, d1, d2 = tensor_product(a, b, mods)
+    d0, d1, d2 = tensor_product(a, b, mods, ctx.pc)
     key = ctx.keys.mult_key
     c0, c1 = ctx.engine.relin(d0, d1, d2, key, lvl)
     digits = ctx.engine.modup(d2, lvl)
@@ -139,7 +139,7 @@ def test_counters_cmult_parity_eager_engine_batched(relin_ctx):
     b_b = Ciphertext(jnp.stack([p[1].c0 for p in cts]),
                      jnp.stack([p[1].c1 for p in cts]), lvl,
                      cts[0][1].scale)
-    d0, d1, d2 = tensor_product(a_b, b_b, mods)
+    d0, d1, d2 = tensor_product(a_b, b_b, mods, ctx.pc)
     s2 = c.snapshot()
     c0b, c1b = ctx.engine.relin_batched(d0, d1, d2, ctx.keys.mult_key,
                                         lvl)
@@ -170,7 +170,7 @@ def test_relin_trace_counts_flat_across_batches(relin_ctx):
                          jnp.stack([p[0].c1 for p in pairs]), lvl, 1.0)
         b_b = Ciphertext(jnp.stack([p[1].c0 for p in pairs]),
                          jnp.stack([p[1].c1 for p in pairs]), lvl, 1.0)
-        d0, d1, d2 = tensor_product(a_b, b_b, mods)
+        d0, d1, d2 = tensor_product(a_b, b_b, mods, ctx.pc)
         ctx.engine.relin_batched(d0, d1, d2, ctx.keys.mult_key, lvl)
     after = ctx.engine.trace_counts[("relin_b", lvl, False)]
     assert after - before == 1      # three same-shape dispatches: 1 trace
@@ -202,7 +202,7 @@ def test_multi_relin_one_moddown(relin_ctx):
     s1 = c.snapshot()
     d0s, d1s, digs = [], [], []
     for a, b in pairs:
-        d0, d1, d2 = tensor_product(a, b, mods)
+        d0, d1, d2 = tensor_product(a, b, mods, ctx.pc)
         d0s.append(d0)
         d1s.append(d1)
         digs.append(ctx.engine.modup(d2, lvl))
@@ -354,7 +354,7 @@ def test_multi_relin_pallas_parity():
         mods = ctx.pc.mods(ctx.chain(lvl))
         d0s, d1s, digs = [], [], []
         for a, bb in pairs:
-            d0, d1, d2 = tensor_product(a, bb, mods)
+            d0, d1, d2 = tensor_product(a, bb, mods, ctx.pc)
             d0s.append(d0)
             d1s.append(d1)
             digs.append(ctx.engine.modup(d2, lvl))
@@ -387,7 +387,7 @@ def _batched_tensor_square(ctx, msgs):
     cts = [ctx.encrypt(m) for m in msgs]
     lvl = cts[0].level
     mods = ctx.pc.mods(ctx.chain(lvl))
-    trips = [tensor_product(a, a, mods) for a in cts]
+    trips = [tensor_product(a, a, mods, ctx.pc) for a in cts]
     return tuple(jnp.stack([t[i] for t in trips]) for i in range(3)), lvl
 
 

@@ -22,6 +22,8 @@ from repro.serve import (
     poisson_trace,
 )
 from repro.serve.metrics import TenantStats
+from repro.serve.queue import RequestQueue
+from repro.serve.scheduler import ContinuousBatcher
 
 N_DIAG_A, BS_A = 4, 2           # program "a": BSGS matvec
 N_DIAG_B = 3                    # program "b": single-block matvec
@@ -168,6 +170,19 @@ def test_eviction_never_evicts_inflight(sctx):
         pass
     assert all(k[0] not in a_ids for k in sctx.engine._evk_level)
     assert all(i not in a_ids for i in sctx.engine._evk_full)
+
+
+@pytest.mark.parametrize("arrival", [0.2, 0.4, 1e3 + 0.3])
+def test_batcher_flushes_at_its_flush_time(arrival):
+    """A lone request is ready at exactly ``next_flush_time`` — the time
+    the idle virtual clock jumps to.  With (arrival + w) - arrival < w
+    in floating point (e.g. 0.2 + 0.05), the batcher once stayed
+    not-ready there forever and ``run_trace`` never returned."""
+    q = RequestQueue(4)
+    q.offer("t", "p", {}, arrival)
+    b = ContinuousBatcher(max_batch=4, max_wait_s=0.05)
+    batch = b.pick(q, b.next_flush_time(q))
+    assert batch is not None and len(batch.requests) == 1
 
 
 def test_metrics_arithmetic():
