@@ -18,6 +18,7 @@ import math
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import poly
 from repro.core.params import CKKSParams
 
@@ -93,34 +94,39 @@ class KeyChain:
         return out
 
     def _gen_evk(self, s_prime_eval: jnp.ndarray) -> EvalKey:
-        """evk for switching s_prime -> s. s_prime_eval: (L+1+k, N) eval."""
-        p, pc = self.params, self.pc
-        primes = self.ext_primes
-        mods = pc.mods(primes)
-        digits = []
-        for j in range(p.dnum):
-            a_rns = np.stack(
-                [
-                    self.rng.integers(0, q, p.N, dtype=np.uint64)
-                    for q in primes
-                ]
-            )
-            a_eval = poly.ntt(jnp.asarray(a_rns), primes, pc)
-            e_rns = to_rns(sample_gaussian(self.rng, p.N), primes)
-            e_eval = poly.ntt(jnp.asarray(e_rns), primes, pc)
-            b = pc.sub(
-                pc.add(
-                    pc.mul_scalar(
-                        s_prime_eval, jnp.asarray(self._gadgets[j]), mods
+        """evk for switching s_prime -> s. s_prime_eval: (L+1+k, N) eval.
+
+        Timed as ``keygen`` by the set-up counters (``obs.SETUP``), and
+        a ``keys.gen_evk`` span while tracing is on."""
+        with obs.span("keys.gen_evk", dnum=self.params.dnum), \
+                obs.SETUP.timed("keygen"):
+            p, pc = self.params, self.pc
+            primes = self.ext_primes
+            mods = pc.mods(primes)
+            digits = []
+            for j in range(p.dnum):
+                a_rns = np.stack(
+                    [
+                        self.rng.integers(0, q, p.N, dtype=np.uint64)
+                        for q in primes
+                    ]
+                )
+                a_eval = poly.ntt(jnp.asarray(a_rns), primes, pc)
+                e_rns = to_rns(sample_gaussian(self.rng, p.N), primes)
+                e_eval = poly.ntt(jnp.asarray(e_rns), primes, pc)
+                b = pc.sub(
+                    pc.add(
+                        pc.mul_scalar(
+                            s_prime_eval, jnp.asarray(self._gadgets[j]), mods
+                        ),
+                        e_eval,
+                        mods,
                     ),
-                    e_eval,
+                    pc.mul(a_eval, self.s_eval, mods),
                     mods,
-                ),
-                pc.mul(a_eval, self.s_eval, mods),
-                mods,
-            )
-            digits.append(jnp.stack([b, a_eval]))
-        return EvalKey(digits=digits)
+                )
+                digits.append(jnp.stack([b, a_eval]))
+            return EvalKey(digits=digits)
 
     # ------------------------------------------------------------------
     @property

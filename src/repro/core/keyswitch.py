@@ -75,7 +75,7 @@ and ``tests/test_relin.py``).
 from __future__ import annotations
 
 import copy
-from functools import partial
+from functools import partial, wraps
 from typing import TYPE_CHECKING
 
 import jax
@@ -99,6 +99,27 @@ if TYPE_CHECKING:  # avoid importing keys at runtime (ckks -> keyswitch)
 # intermediate — the VMEM-resident working-set analogue of the Pallas
 # BConvU's coefficient blocking.
 _CHUNK = 8
+
+
+def plan_name(kind: str, level: int) -> str:
+    """Name of a jitted keyswitch plan, ``ks_<kind>_L<level>``: the same
+    in every process, so a warm run finds it in the persistent cache,
+    and in the compiled program's ops (``jit(ks_galois_L7)/...``)."""
+    return f"ks_{kind}_L{level}"
+
+
+def _scoped(scope: str):
+    """Trace a keyswitch phase under ``jax.named_scope(scope)``: its ops
+    carry the scope in their op names, and so on the device trace.  The
+    phases are ``ks.modup``, ``ks.ip``, ``ks.moddown`` and
+    ``ks.automorph`` (the eval-domain gathers, scoped inline)."""
+    def wrap(fn):
+        @wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(scope):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
 
 
 def ext_rows(params, level: int) -> np.ndarray:
@@ -267,12 +288,9 @@ class KeyswitchEngine:
         return self._plans[level]
 
     def _count_trace(self, key: tuple) -> None:
-        n = self.trace_counts.get(key, 0) + 1
-        self.trace_counts[key] = n
         # a repeat trace of the same plan key is a retrace — exactly
         # what the serving layer's zero-retrace gate hunts for
-        obs.event("engine.jit_trace", key=str(key), count=n,
-                  retrace=n > 1)
+        self.trace_counts[key] = self.trace_counts.get(key, 0) + 1
 
     def _note_dispatch(self, op: str) -> None:
         """Kernel-dispatch event: lets Perfetto traces tell a pallas
@@ -315,7 +333,6 @@ class KeyswitchEngine:
         if key not in self._evk_full:
             self._admit_evk(evk)
             self._evk_full[key] = (evk, jnp.stack(evk.digits))
-            obs.event("engine.evk_admit", cached=len(self._evk_full))
         return self._evk_full[key][1]
 
     def evk_tensor(self, evk: EvalKey, level: int) -> jnp.ndarray:
@@ -374,6 +391,7 @@ class KeyswitchEngine:
             return poly.fence(y.astype(jnp.uint64))
         return poly.intt(x, primes, plan.pc)
 
+    @_scoped("ks.modup")
     def _modup(self, a, plan: KeyswitchPlan):
         """(l, N) eval -> (dnum, l_ext, N) eval, all digits at once."""
         modq = self.pc.modq
@@ -414,6 +432,7 @@ class KeyswitchEngine:
         own = a[plan.own_idx]                          # (dnum, l_ext, N)
         return jnp.where(plan.own_mask[:, :, None], own, conv)
 
+    @_scoped("ks.ip")
     def _ip(self, digits, evk, plan: KeyswitchPlan):
         """(dnum, l_ext, N) x (dnum, 2, l_ext, N) -> (2, l_ext, N)."""
         if self.backend == "pallas":
@@ -427,6 +446,7 @@ class KeyswitchEngine:
         prod = modq(digits[:, None] * evk, em)        # (dnum, 2, l_ext, N)
         return modq(prod.sum(axis=0), em[0])
 
+    @_scoped("ks.moddown")
     def _moddown2(self, acc, plan: KeyswitchPlan):
         """Batched ModDown of both accumulators: (2, l_ext, N) -> (2, l, N)."""
         modq = self.pc.modq
@@ -461,14 +481,19 @@ class KeyswitchEngine:
         return modq(diff * plan.pinv[None, :, None], bm)
 
     # ------------------------- jitted entry points ---------------------
-    def _jit(self, level: int, fn):
-        """``jax.jit`` of ``fn(plan, *args)`` over ``level``'s plan.
+    def _jit(self, kind: str, level: int, fn):
+        """``jax.jit`` of ``fn(plan, *args)`` over ``level``'s plan,
+        named ``ks_<kind>_L<level>`` (``plan_name``).
 
         The context's tables are an argument of the compiled program,
         bound onto the plan inside the trace (``PolyContext.bind``)."""
         plan = self._plan(level)
-        jitted = jax.jit(lambda tables, *args: fn(plan.bind(tables), *args))
-        return partial(jitted, self.pc.tables)
+
+        def run(tables, *args):
+            return fn(plan.bind(tables), *args)
+
+        run.__name__ = run.__qualname__ = plan_name(kind, level)
+        return partial(jax.jit(run), self.pc.tables)
 
     def _ks_fn(self, level: int):
         if level not in self._ks_fns:
@@ -478,19 +503,21 @@ class KeyswitchEngine:
                 d = self._moddown2(self._ip(digits, evk, plan), plan)
                 return d[0], d[1]
 
-            self._ks_fns[level] = self._jit(level, fn)
+            self._ks_fns[level] = self._jit("keyswitch", level, fn)
         return self._ks_fns[level]
 
     def _galois_fn(self, level: int):
         if level not in self._galois_fns:
             def fn(plan, c0, c1, perm, evk):
                 self._count_trace(("galois", level))
-                digits = self._modup(c1[:, perm], plan)
+                with jax.named_scope("ks.automorph"):
+                    c0p, c1p = c0[:, perm], c1[:, perm]
+                digits = self._modup(c1p, plan)
                 d = self._moddown2(self._ip(digits, evk, plan), plan)
                 bm = plan.base_mods[:, None]
-                return self.pc.modq(c0[:, perm] + d[0], bm), d[1]
+                return self.pc.modq(c0p + d[0], bm), d[1]
 
-            self._galois_fns[level] = self._jit(level, fn)
+            self._galois_fns[level] = self._jit("galois", level, fn)
         return self._galois_fns[level]
 
     def _hoist_core(self, plan: KeyswitchPlan, n_rot: int, with_pt: bool,
@@ -500,28 +527,31 @@ class KeyswitchEngine:
         digits-in and vmap-batched entry points (bit-exact across all)."""
         modq = self.pc.modq
         # One gather rotates ALL digits for ALL rotations.
-        d_rot = jnp.transpose(
-            digits[:, :, perms], (2, 0, 1, 3)
-        )                                      # (R, dnum, l_ext, N)
+        with jax.named_scope("ks.automorph"):
+            d_rot = jnp.transpose(
+                digits[:, :, perms], (2, 0, 1, 3)
+            )                                  # (R, dnum, l_ext, N)
         em = plan.ext_mods[None, :, None]
-        if self.backend == "pallas":
-            acc = None
-            for r in range(n_rot):
-                a0, a1 = fused_ip_mont(
-                    d_rot[r].astype(jnp.uint32), evk_all[r],
-                    pm_ext_m[r] if with_pt else None,
-                    plan.q32, plan.qneg32, interpret=self.interpret,
-                )
-                ipr = jnp.stack([a0, a1]).astype(jnp.uint64)
-                acc = ipr if acc is None else modq(acc + ipr, em)
-        else:
-            prod = modq(d_rot[:, :, None] * evk_all, em[None, None])
-            ip = modq(prod.sum(axis=1), em[None])       # (R, 2, l_ext, N)
-            if with_pt:
-                ip = modq(ip * pm_ext[:, None], em[None])
-            acc = modq(ip.sum(axis=0), em)
+        with jax.named_scope("ks.ip"):
+            if self.backend == "pallas":
+                acc = None
+                for r in range(n_rot):
+                    a0, a1 = fused_ip_mont(
+                        d_rot[r].astype(jnp.uint32), evk_all[r],
+                        pm_ext_m[r] if with_pt else None,
+                        plan.q32, plan.qneg32, interpret=self.interpret,
+                    )
+                    ipr = jnp.stack([a0, a1]).astype(jnp.uint64)
+                    acc = ipr if acc is None else modq(acc + ipr, em)
+            else:
+                prod = modq(d_rot[:, :, None] * evk_all, em[None, None])
+                ip = modq(prod.sum(axis=1), em[None])   # (R, 2, l_ext, N)
+                if with_pt:
+                    ip = modq(ip * pm_ext[:, None], em[None])
+                acc = modq(ip.sum(axis=0), em)
         bm = plan.base_mods[None, :, None]
-        c0r = jnp.transpose(c0[:, perms], (1, 0, 2))  # (R, l, N)
+        with jax.named_scope("ks.automorph"):
+            c0r = jnp.transpose(c0[:, perms], (1, 0, 2))  # (R, l, N)
         if with_pt:
             c0r = modq(c0r * pm_base, bm)
         base0 = modq(c0r.sum(axis=0), plan.base_mods[:, None])
@@ -538,7 +568,7 @@ class KeyswitchEngine:
                                         perms, evk_all, pm_ext, pm_base,
                                         pm_ext_m)
 
-            self._hoist_fns[key] = self._jit(level, fn)
+            self._hoist_fns[key] = self._jit("hoisted", level, fn)
         return self._hoist_fns[key]
 
     def _hoist_digits_fn(self, level: int, n_rot: int, with_pt: bool):
@@ -553,9 +583,10 @@ class KeyswitchEngine:
                                         perms, evk_all, pm_ext, pm_base,
                                         pm_ext_m)
 
-            self._hoist_fns[key] = self._jit(level, fn)
+            self._hoist_fns[key] = self._jit("hoisted_digits", level, fn)
         return self._hoist_fns[key]
 
+    @_scoped("ks.ip")
     def _acc_ip_ext(self, plan: KeyswitchPlan, n: int, d_terms, evk_all):
         """sum_r IP(d_terms[r], evk_all[r]) in the extended basis.
 
@@ -588,10 +619,11 @@ class KeyswitchEngine:
         by ITS perm, IP against ITS evk, accumulate every term in the
         extended basis, and close with ONE batched ModDown."""
         modq = self.pc.modq
-        d_rot = jax.vmap(lambda d, p: d[:, :, p])(digits, perms)
+        with jax.named_scope("ks.automorph"):
+            d_rot = jax.vmap(lambda d, p: d[:, :, p])(digits, perms)
+            c0r = jax.vmap(lambda c, p: c[:, p])(c0s, perms)   # (n, l, N)
         acc = self._acc_ip_ext(plan, n, d_rot, evk_all)
         bm = plan.base_mods[:, None]
-        c0r = jax.vmap(lambda c, p: c[:, p])(c0s, perms)   # (n, l, N)
         base0 = modq(c0r.sum(axis=0), bm)
         d = self._moddown2(acc, plan)
         return modq(base0 + d[0], bm), d[1]
@@ -604,7 +636,7 @@ class KeyswitchEngine:
                 return self._multi_core(plan, n, c0s, digits, perms,
                                         evk_all)
 
-            self._hoist_fns[key] = self._jit(level, fn)
+            self._hoist_fns[key] = self._jit("multi_hoisted", level, fn)
         return self._hoist_fns[key]
 
     # ------------------------- relinearization -------------------------
@@ -628,7 +660,7 @@ class KeyswitchEngine:
                 digits = x if digits_in else self._modup(x, plan)
                 return self._relin_core(plan, d0, d1, digits, evk)
 
-            self._hoist_fns[key] = self._jit(level, fn)
+            self._hoist_fns[key] = self._jit("relin", level, fn)
         return self._hoist_fns[key]
 
     def _multi_relin_core(self, plan: KeyswitchPlan, n: int, d0s, d1s,
@@ -653,7 +685,7 @@ class KeyswitchEngine:
                 return self._multi_relin_core(plan, n, d0s, d1s, digits,
                                               evk)
 
-            self._hoist_fns[key] = self._jit(level, fn)
+            self._hoist_fns[key] = self._jit("multi_relin", level, fn)
         return self._hoist_fns[key]
 
     def _modup_fn(self, level: int):
@@ -662,17 +694,17 @@ class KeyswitchEngine:
                 self._count_trace(("modup", level))
                 return self._modup(a, plan)
 
-            self._modup_fns[level] = self._jit(level, fn)
+            self._modup_fns[level] = self._jit("modup", level, fn)
         return self._modup_fns[level]
 
     # ------------------------- batched (vmap) fns ----------------------
     def _batched_fn(self, key: tuple, fn):
         """jit(vmap) plan cache: one trace per (op, level, shape) plan —
         re-dispatch at the same batch shape is a cache hit (asserted by
-        ``trace_counts``, which only increments while tracing).  ``key[1]``
-        is the plan's level."""
+        ``trace_counts``, which only increments while tracing).  ``key``
+        is ``(kind, level, ...)``."""
         if key not in self._batch_fns:
-            self._batch_fns[key] = self._jit(key[1], fn)
+            self._batch_fns[key] = self._jit(key[0], key[1], fn)
         return self._batch_fns[key]
 
     def _ks_batched_fn(self, level: int):
@@ -694,9 +726,11 @@ class KeyswitchEngine:
             bm = plan.base_mods[:, None]
 
             def one(c0, c1):
-                digits = self._modup(c1[:, perm], plan)
+                with jax.named_scope("ks.automorph"):
+                    c0p, c1p = c0[:, perm], c1[:, perm]
+                digits = self._modup(c1p, plan)
                 d = self._moddown2(self._ip(digits, evk, plan), plan)
-                return self.pc.modq(c0[:, perm] + d[0], bm), d[1]
+                return self.pc.modq(c0p + d[0], bm), d[1]
 
             return jax.vmap(one)(c0b, c1b)
 

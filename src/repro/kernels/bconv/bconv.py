@@ -67,6 +67,7 @@ def bconv_pallas(x, qhat_inv_mont, src_q, src_qneg, c_mont, dst_q, dst_qneg,
 
     t = pl.pallas_call(
         functools.partial(_scale_kernel, ls=ls),
+        name="bconv",
         grid=(rows,),
         in_specs=[smem_spec(), smem_spec(), smem_spec(),
                   block_spec((1, r, lanes), lambda i: (i, 0, 0))],
@@ -79,6 +80,7 @@ def bconv_pallas(x, qhat_inv_mont, src_q, src_qneg, c_mont, dst_q, dst_qneg,
 
     return pl.pallas_call(
         functools.partial(_reduce_kernel, ls=ls, ld=ld),
+        name="bconv_reduce",
         grid=(b * ld, r // rb),
         in_specs=[
             smem_spec(2), smem_spec(), smem_spec(),

@@ -76,6 +76,7 @@ def fused_ip_pallas(digits, evk_mont, pt_mont, q, qneg,
     out = jax.ShapeDtypeStruct((rows, r, lanes), jnp.uint32)
     return pl.pallas_call(
         kernel,
+        name="fused_ip",
         grid=(rows,),
         in_specs=in_specs,
         out_specs=[limb, limb],

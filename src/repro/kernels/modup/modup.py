@@ -118,6 +118,7 @@ def modup_pallas(x, twist_i_scaled, tw_i, src_q, src_qneg,
     kernel = functools.partial(_modup_kernel, ls=ls, ld=ld, logn=logn)
     return pl.pallas_call(
         kernel,
+        name="modup",
         grid=(b * ld,),
         in_specs=[
             smem_spec(), smem_spec(), smem_spec(2), smem_spec(), smem_spec(),

@@ -259,6 +259,7 @@ def ntt_pallas(x, twist, tw, q, qinv, *, logn: int, inverse: bool,
     kernel = functools.partial(_ntt_kernel, l=l, logn=logn, inverse=inverse)
     return pl.pallas_call(
         kernel,
+        name="intt" if inverse else "ntt",
         grid=(rows,),
         in_specs=[
             smem_spec(), smem_spec(),

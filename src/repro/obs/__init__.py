@@ -1,10 +1,14 @@
 """Unified observability for the HE^2 repro: spans, metrics, Perfetto.
 
-One process-global tracer and metrics registry, opt-in and stdlib-only.
+One process-global tracer and metrics registry, opt-in.
 Instrumented modules (``runtime``, ``core``, ``serve``) call the
 module-level helpers here; when disabled each call is a branch and a
 no-op return, adds zero jit retraces, and costs <2% of end-to-end
 runtime (gated in ``benchmarks/bench_bootstrap.py``).
+
+While enabled, spans also reach a running ``jax.profiler`` trace as
+annotations on the host plane.  ``SETUP`` counts set-up's tracing,
+compiling and key generation at all times (:mod:`repro.obs.setup`).
 
 Typical use::
 
@@ -17,7 +21,7 @@ Typical use::
     print(obs.METRICS.to_text())
 """
 
-from . import budget, export, registry, tracer  # noqa: F401  (re-export)
+from . import budget, export, registry, setup, tracer  # noqa: F401
 from .budget import PAPER_STALL_BUDGET, StallBudget, analyze  # noqa: F401
 from .registry import (  # noqa: F401
     MetricsRegistry,
@@ -25,6 +29,7 @@ from .registry import (  # noqa: F401
     publish_energy,
     publish_serving,
 )
+from .setup import SetupCounters  # noqa: F401
 from .tracer import NULL_SPAN, Span, Tracer  # noqa: F401
 
 #: Process-global tracer; disabled until :func:`enable` is called.
@@ -32,6 +37,11 @@ TRACER = Tracer()
 
 #: Process-global metrics registry.
 METRICS = MetricsRegistry()
+
+#: Process-global set-up counters, listening to ``jax.monitoring`` from
+#: this import on, whether or not tracing is enabled.
+SETUP = SetupCounters()
+SETUP.register()
 
 
 def enabled() -> bool:

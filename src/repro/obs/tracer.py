@@ -11,10 +11,14 @@ Design constraints (ISSUE 8):
 * **Thread-safe context propagation** — the current-span stack lives in
   ``threading.local`` so serve-loop worker threads nest correctly, while
   finished spans land in one lock-guarded list for export.
+* **One clock with the device** — while enabled, every span also enters
+  a ``jax.profiler.TraceAnnotation`` of the same name (its scalar
+  attributes as the annotation's stats), so a profiler trace holds the
+  program's spans on the host plane beside the device ops.
 
 Spans record ``time.perf_counter_ns`` timestamps, structured attributes
 (``set_attrs``) and point events (``event``).  Export to Perfetto is in
-:mod:`repro.obs.export`; this module is stdlib-only.
+:mod:`repro.obs.export`; JAX is imported only when a tracer is enabled.
 """
 
 from __future__ import annotations
@@ -41,6 +45,7 @@ class Span:
         "attrs",
         "events",
         "_tracer",
+        "_note",
     )
 
     def __init__(
@@ -61,6 +66,7 @@ class Span:
         self.end_ns: Optional[int] = None
         self.attrs = attrs
         self.events: List[Tuple[str, int, Dict[str, Any]]] = []
+        self._note = None
 
     # -- structured payload -------------------------------------------------
     def set_attrs(self, **attrs: Any) -> "Span":
@@ -74,11 +80,19 @@ class Span:
     # -- lifecycle ----------------------------------------------------------
     def __enter__(self) -> "Span":
         self._tracer._push(self)
+        profiler = self._tracer._profiler
+        if profiler is not None:
+            self._note = profiler.TraceAnnotation(
+                self.name, **{k: v for k, v in self.attrs.items()
+                              if isinstance(v, (bool, int, float, str))})
+            self._note.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
         self.end_ns = self._tracer.clock()
         self._tracer._pop(self)
 
@@ -132,6 +146,8 @@ class Tracer:
     def __init__(self, clock=time.perf_counter_ns):
         self.enabled = False
         self.clock = clock
+        # ``jax.profiler`` while enabled: spans annotate the profiler
+        self._profiler = None
         self._lock = threading.Lock()
         self._tls = threading.local()
         self._next_id = 0
@@ -141,10 +157,14 @@ class Tracer:
 
     # -- control ------------------------------------------------------------
     def enable(self) -> None:
+        import jax.profiler
+
+        self._profiler = jax.profiler
         self.enabled = True
 
     def disable(self) -> None:
         self.enabled = False
+        self._profiler = None
 
     def reset(self) -> None:
         with self._lock:

@@ -110,14 +110,11 @@ class TraceContext:
         h = self._emit(OpKind.INPUT, (), level, scale, tag=tag)
         self.g.nodes[h.nid].attrs["level"] = level
         self.inputs[tag] = h.nid
-        obs.event("trace.input", tag=tag, level=level, nid=h.nid)
         return h
 
     def output(self, h: TraceHandle, tag: str = "out") -> int:
         nid = self.g.add(OpKind.OUTPUT, (h.nid,), limbs=h.n_limbs, tag=tag)
         self.outputs[tag] = h.nid
-        obs.event("trace.output", tag=tag, nid=nid,
-                  nodes=len(self.g.nodes))
         return nid
 
     # ------------------------- encode ----------------------------------

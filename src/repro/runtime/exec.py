@@ -31,6 +31,7 @@ front and right-pad partial batches to the nearest warmed width
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from functools import partial
 
@@ -106,6 +107,8 @@ class ProgramExecutor:
         self._pins: dict[int, CompiledProgram] = {}
         self._pins_max = 32
         self._rescale_fns: dict[int, object] = {}
+        # dispatches so far: a run's ``batch_index``, shared by its spans
+        self._runs = 0
 
     def _pin(self, compiled: CompiledProgram) -> None:
         if id(compiled) in self._pins:
@@ -147,37 +150,40 @@ class ProgramExecutor:
         """Execute over B independent ciphertexts per input at once."""
         if not self.ctx.use_engine:
             raise NotImplementedError("batched execution needs the engine")
-        batch = None
-        stacked = {}
-        for tag, cts in inputs.items():
-            if len({(c.level, c.scale) for c in cts}) != 1:
-                raise ModulusChainMismatchError(
-                    f"batched inputs for '{tag}' mix levels/scales",
-                    hint="a batch must be homogeneous; split mixed-"
-                         "level requests into separate dispatches",
-                    tag=tag,
-                    levels=sorted({c.level for c in cts}),
-                    scales=sorted({c.scale for c in cts}))
-            batch = len(cts) if batch is None else batch
-            if len(cts) != batch:
-                raise InvalidRequestError(
-                    f"input '{tag}' has {len(cts)} ciphertexts but the "
-                    f"batch width is {batch}",
-                    hint="every input tag must carry one ciphertext "
-                         "per batch slot",
-                    tag=tag)
-            stacked[tag] = Ciphertext(
-                jnp.stack([c.c0 for c in cts]),
-                jnp.stack([c.c1 for c in cts]),
-                cts[0].level, cts[0].scale,
-            )
+        run_id = self._runs
+        with obs.span("exec.batch", batch_index=run_id, phase="stack"):
+            batch = None
+            stacked = {}
+            for tag, cts in inputs.items():
+                if len({(c.level, c.scale) for c in cts}) != 1:
+                    raise ModulusChainMismatchError(
+                        f"batched inputs for '{tag}' mix levels/scales",
+                        hint="a batch must be homogeneous; split mixed-"
+                             "level requests into separate dispatches",
+                        tag=tag,
+                        levels=sorted({c.level for c in cts}),
+                        scales=sorted({c.scale for c in cts}))
+                batch = len(cts) if batch is None else batch
+                if len(cts) != batch:
+                    raise InvalidRequestError(
+                        f"input '{tag}' has {len(cts)} ciphertexts but the "
+                        f"batch width is {batch}",
+                        hint="every input tag must carry one ciphertext "
+                             "per batch slot",
+                        tag=tag)
+                stacked[tag] = Ciphertext(
+                    jnp.stack([c.c0 for c in cts]),
+                    jnp.stack([c.c1 for c in cts]),
+                    cts[0].level, cts[0].scale,
+                )
         res = self._run(compiled, stacked, batch=batch,
                         with_report=with_report, validate=validate)
-        outputs = {
-            tag: [Ciphertext(ct.c0[b], ct.c1[b], ct.level, ct.scale)
-                  for b in range(batch)]
-            for tag, ct in res.outputs.items()
-        }
+        with obs.span("exec.batch", batch_index=run_id, phase="split"):
+            outputs = {
+                tag: [Ciphertext(ct.c0[b], ct.c1[b], ct.level, ct.scale)
+                      for b in range(batch)]
+                for tag, ct in res.outputs.items()
+            }
         return ExecResult(outputs, res.report)
 
     # ------------------------- execution loop --------------------------
@@ -198,13 +204,16 @@ class ProgramExecutor:
         # Prefetch the enabled flag once: the disabled hot path is one
         # boolean per step (plus the no-op run span below).
         tracing = obs.TRACER.enabled
+        run_id = self._runs
+        self._runs += 1
         frees = _last_reads(compiled)
-        with obs.span("exec.run", batch=batch,
+        with obs.span("exec.run", batch=batch, batch_index=run_id,
                       n_steps=len(compiled.steps), validate=validate):
             for step, dead in zip(compiled.steps, frees):
                 if tracing:
-                    self._exec_step_traced(compiled, step, values, digits,
-                                           outputs, inputs, batch, validate)
+                    with self._step_span(compiled, step, batch, run_id):
+                        self._exec_step(compiled, step, values, digits,
+                                        outputs, inputs, batch, validate)
                 else:
                     self._exec_step(compiled, step, values, digits,
                                     outputs, inputs, batch, validate)
@@ -250,28 +259,24 @@ class ProgramExecutor:
             return type(step).__name__, step.out
         return compiled.dfg.nodes[step.nid].op.value, step.nid
 
-    def _exec_step_traced(self, compiled, step, values, digits, outputs,
-                          inputs, batch: int, validate: bool) -> None:
-        """Tracing mirror of ``_exec_step``: one span per step carrying
-        the real wall clock (``block_until_ready`` on the produced ct —
-        a device sync, which is why this path is opt-in) and the op
-        counts the step actually incremented.  The dispatched code is
-        byte-identical, so jit plan caches see the same trace keys."""
+    @contextlib.contextmanager
+    def _step_span(self, compiled, step, batch: int, run_id: int):
+        """``exec.step.<kind>`` span around one step: the op counts the
+        step incremented, and the profiler annotation of the same name
+        (``obs.span``).  The device is not waited on: the span is the
+        host's dispatch of the step, and the dispatched code is the
+        untraced path's, so jit plan caches see the same trace keys."""
         ctx = self.ctx
         label, out_id = self._step_label(compiled, step)
         before = ctx.counters.snapshot()
         eng = ctx.engine if ctx.use_engine else None
         with obs.span(f"exec.step.{label}", out=out_id, batch=batch,
+                      batch_index=run_id,
                       level=getattr(step, "level", None),
                       backend=eng.backend if eng else "none",
                       interpret=bool(eng and eng.backend == "pallas"
                                      and eng.interpret)) as sp:
-            self._exec_step(compiled, step, values, digits, outputs,
-                            inputs, batch, validate)
-            out = values.get(out_id)
-            if out is not None:
-                jax.block_until_ready(out.c0)
-                jax.block_until_ready(out.c1)
+            yield
             d = ctx.counters.delta(before)
             sp.set_attrs(modup=d.modup, moddown=d.moddown, ip=d.ip,
                          keyswitch=d.keyswitch, relin=d.relin)
